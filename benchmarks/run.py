@@ -12,6 +12,9 @@ import sys
 import time
 import traceback
 
+from repro.hostenv import use_compile_cache
+use_compile_cache()                         # before anything imports jax
+
 from benchmarks import common
 
 REGISTRY = [

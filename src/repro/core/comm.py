@@ -29,17 +29,6 @@ import jax
 import jax.numpy as jnp
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: >= 0.5 exposes it at top level
-    (``check_vma``); 0.4.x has ``jax.experimental.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def pad_to(x, rows: int, axis: int = 0):
     pad = rows - x.shape[axis]
     if pad <= 0:

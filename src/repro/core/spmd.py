@@ -169,7 +169,6 @@ def make_interval_step(cfg: DiTConfig, sched: NoiseSchedule,
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import sampler as sampler_lib
-    from repro.core.comm import shard_map_compat
 
     if exchange_kind not in ("full", "skip"):
         raise ValueError(f"make_interval_step compiles 'full' or 'skip' "
@@ -206,7 +205,8 @@ def make_interval_step(cfg: DiTConfig, sched: NoiseSchedule,
             pub_k, pub_v, merge_kv=(exchange_kind == "full"))
         return x_full, pub_k[:, :, :cfg.n_tokens], pub_v[:, :, :cfg.n_tokens]
 
-    fn = shard_map_compat(body, mesh, (P(),) * 6, (P(), P(), P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(),) * 6,
+                       out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -236,7 +236,7 @@ def run_spmd_pipefuse(params, cfg: DiTConfig, sched: NoiseSchedule, x_T,
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.core import sampler as sampler_lib
-    from repro.core.comm import shard_map_compat, stage_handoff
+    from repro.core.comm import stage_handoff
     from repro.core.schedule import patch_bounds
     from repro.models.diffusion import dit
 
@@ -373,7 +373,8 @@ def run_spmd_pipefuse(params, cfg: DiTConfig, sched: NoiseSchedule, x_T,
                 # skip/predict: the pipe stays full; context persists
         return x_full
 
-    fn = shard_map_compat(body, mesh, (P(), P(), P()), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(params, x_T, cond)
 
 
@@ -504,8 +505,8 @@ def run_spmd(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                         read_k, read_v = pub_k, pub_v
         return x_full
 
-    from repro.core.comm import shard_map_compat
-    fn = shard_map_compat(body, mesh, (P(), P(), P()), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(params, x_T, cond)
 
 
@@ -727,8 +728,8 @@ def run_spmd_seq(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                         read_k, read_v = pub_k, pub_v
         return x_full
 
-    from repro.core.comm import shard_map_compat
-    fn = shard_map_compat(body, mesh, (P(), P(), P()), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(params, x_T, cond)
 
 
@@ -947,8 +948,8 @@ def run_spmd_frames(params, cfg: DiTConfig, sched: NoiseSchedule, x_T,
         return jnp.stack([from_row(row_of[f], xs[f]) for f in range(F)],
                          axis=1)
 
-    from repro.core.comm import shard_map_compat
-    fn = shard_map_compat(body, mesh, (P(), P(), P()), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(params, x_T, cond)
 
 
@@ -1081,6 +1082,6 @@ def run_spmd_guidance(params, cfg: DiTConfig, sched: NoiseSchedule, x_T,
                         read_k, read_v = pub_k, pub_v
         return x_full
 
-    from repro.core.comm import shard_map_compat
-    fn = shard_map_compat(body, mesh, (P(), P(), P()), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(params, x_T, cond)

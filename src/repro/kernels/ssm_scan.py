@@ -18,9 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x compat: CompilerParams was named TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, dskip_ref, o_ref,
                 h_ref, *, chunk: int, n_chunks: int):
@@ -76,7 +73,7 @@ def ssm_scan_chunked(x, dt, b_t, c_t, a, d_skip, *, chunk: int = 64,
         out_specs=pl.BlockSpec((1, chunk, dblk), lambda bb, d, c: (bb, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, Di), x.dtype),
         scratch_shapes=[pltpu.VMEM((dblk, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b_t, c_t, a, d_skip)

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x compat: CompilerParams was named TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -109,7 +106,7 @@ def stale_kv_attention_bhsd(q_fresh, k_fresh, v_fresh, k_stale, v_stale,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_fresh, k_fresh, v_fresh, k_stale, v_stale)
@@ -216,7 +213,7 @@ def stale_kv_attention_padded_bhsd(q_fresh, k_fresh, v_fresh, k_stale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Nlm, hd), q_fresh.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -323,7 +320,7 @@ def stale_kv_attention_guided_bhsd(q_fresh, k_fresh, v_fresh, k_stale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, B, H, Nlm, hd), q_fresh.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
@@ -404,7 +401,7 @@ def lse_attention_bhsd(q, k, v, valid_len, *, scale=None, bq: int = 8,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
                    jax.ShapeDtypeStruct((B, H, S), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

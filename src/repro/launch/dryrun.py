@@ -57,8 +57,6 @@ def run_one(arch: str, shape: str, multi_pod: bool, verbose: bool = True) -> dic
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):      # jax 0.4.x returns [dict]
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
     coll = rl.collective_bytes(hlo)
     roof = rl.build(arch, shape, mesh_name, chips, cost, coll)

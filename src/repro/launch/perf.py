@@ -102,8 +102,6 @@ def run_variant(arch: str, shape_name: str, variants: str,
         lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         compiled = lowered.compile()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):      # jax 0.4.x returns [dict]
-            cost = cost[0] if cost else {}
         coll = rl.collective_bytes(compiled.as_text())
         mem = compiled.memory_analysis()
     sh._RULES.clear()

@@ -5,13 +5,16 @@ diffusion request queue through the continuous-batching
   PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b --requests 8
   PYTHONPATH=src python -m repro.launch.serve --diffusion --arch tiny-dit \
       --occupancies 0.0,0.6 --requests 8 --slots 4 --slo-ms 200
+  PYTHONPATH=src python -m repro.launch.serve --diffusion --arch sdxl-dit \
+      --use-pallas --m-base 20 --m-warmup 4 --slots 2 --requests 3
   STADI_HOST_DEVICES=2 PYTHONPATH=src python -m repro.launch.serve \
       --diffusion --backend spmd --requests 4
 """
 from __future__ import annotations
 
-from repro.hostenv import force_host_devices
+from repro.hostenv import force_host_devices, use_compile_cache
 force_host_devices()                        # --backend spmd on CPU hosts
+use_compile_cache()
 
 import argparse
 import time
@@ -51,20 +54,25 @@ def serve(arch: str, *, n_requests: int = 8, slots: int = 4,
 def serve_diffusion(arch: str = "tiny-dit", *, occupancies=(0.0, 0.6),
                     n_requests: int = 4, slots: int = 4, m_base: int = 16,
                     m_warmup: int = 4, planner: str = "stadi",
-                    backend: str = "emulated", reduced: bool = True,
+                    backend: str = "emulated", reduced: bool = False,
                     slo_s: float = None, seed: int = 0,
                     exchange: str = "sync", exchange_refresh: int = 2,
                     num_stages: int = 1, cfg_scale: float = 0.0,
                     seq_shards: int = 1, num_frames: int = 1,
                     frame_groups: int = 0, plan_cache_dir: str = None,
                     prompt: str = None, cond_tokens: int = None,
-                    cond_seq_len: int = 32):
+                    cond_seq_len: int = 32,
+                    use_pallas_attention: bool = False, params=None):
     """Continuous batching on a heterogeneous cluster: requests enter a FIFO
     queue, the :class:`DiffusionServingEngine` admits them into ``slots``
     concurrent lanes and drains the queue with batched denoise rounds.
     ``cfg_scale > 0`` makes every other request a classifier-free-guidance
     one (DESIGN.md §12) — the mixed CFG / non-CFG workload the engine's
-    per-lane guidance state exists for."""
+    per-lane guidance state exists for. ``params`` are the denoiser weights
+    for the (text-conditioned, if a prompt is given) model config; None
+    draws ``dit.init_params`` from ``seed``. Returns the drained engine
+    (``engine.completed`` holds the requests, ``engine.pipeline`` the
+    pipeline that served them)."""
     from repro.core import sampler as sampler_lib
     from repro.core.pipeline import StadiConfig, StadiPipeline
     from repro.models.diffusion import dit
@@ -76,7 +84,8 @@ def serve_diffusion(arch: str = "tiny-dit", *, occupancies=(0.0, 0.6),
     text_mode = prompt is not None or cond_tokens is not None
     if text_mode:                          # prompt lanes (DESIGN.md §17)
         cfg = cfg.text_conditioned(cond_seq_len=cond_seq_len)
-    params = dit.init_params(jax.random.PRNGKey(seed), cfg)
+    if params is None:
+        params = dit.init_params(jax.random.PRNGKey(seed), cfg)
     sched = sampler_lib.linear_schedule(T=1000)
     config = StadiConfig.from_occupancies(list(occupancies), m_base=m_base,
                                           m_warmup=m_warmup, planner=planner,
@@ -86,7 +95,9 @@ def serve_diffusion(arch: str = "tiny-dit", *, occupancies=(0.0, 0.6),
                                           seq_shards=seq_shards,
                                           num_frames=num_frames,
                                           frame_groups=frame_groups,
-                                          plan_cache_dir=plan_cache_dir)
+                                          plan_cache_dir=plan_cache_dir,
+                                          use_pallas_attention=(
+                                              use_pallas_attention))
     pipe = StadiPipeline(cfg, params, sched, config)
     engine = DiffusionServingEngine(pipe, slots=slots)
     rng = np.random.default_rng(seed)
@@ -130,6 +141,10 @@ def serve_diffusion(arch: str = "tiny-dit", *, occupancies=(0.0, 0.6),
           f"slots={slots} rounds={stats['rounds']} "
           f"patches={engine.plan.patches} stages={engine.stages} "
           f"seq={engine.seq} frames={engine.frames}")
+    if use_pallas_attention:
+        # trace-time kernel path counters (DESIGN.md §15): did the lane
+        # programs this engine compiled contain the kernels?
+        print(f"  kernel_stats={stats['kernels']}")
     if stats["plan_cache"] is not None:
         c = stats["plan_cache"]
         print(f"  plan cache: {c['hits']} hits / {c['misses']} misses "
@@ -141,7 +156,7 @@ def serve_diffusion(arch: str = "tiny-dit", *, occupancies=(0.0, 0.6),
         print(f"  req {r['uid']}: queued {r['queue_rounds']} rounds, "
               f"served {r['service_rounds']} rounds, modeled latency "
               f"{r['modeled_latency_s']*1e3:.1f} ms{slo}")
-    return done
+    return engine
 
 
 def main():
@@ -218,6 +233,13 @@ def main():
     ap.add_argument("--cond-seq-len", type=int, default=32,
                     help="text-conditioned models: max prompt bucket "
                          "(DiTConfig.cond_seq_len)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="diffusion only: serve the reduced-width model "
+                         "(default: the config's published widths)")
+    ap.add_argument("--use-pallas", action="store_true",
+                    help="diffusion only: route attention + CFG epilogue "
+                         "through the Pallas kernels (DESIGN.md §15; "
+                         "interpret mode off-TPU)")
     args = ap.parse_args()
     if args.diffusion:
         if args.arch == ap.get_default("arch"):
@@ -241,7 +263,9 @@ def main():
                         frame_groups=args.frame_groups,
                         plan_cache_dir=args.plan_cache,
                         prompt=args.prompt, cond_tokens=args.cond_tokens,
-                        cond_seq_len=args.cond_seq_len)
+                        cond_seq_len=args.cond_seq_len,
+                        reduced=args.reduced,
+                        use_pallas_attention=args.use_pallas)
     else:
         if args.prompt is not None or args.cond_tokens is not None:
             ap.error("--prompt/--cond-tokens are diffusion-only "

@@ -1,6 +1,3 @@
-from repro.hostenv import force_host_devices
-force_host_devices()
-
 """STADI inference driver — the paper's system (launchable).
 
 Thin CLI over :class:`repro.core.pipeline.StadiPipeline`; strategy selection
@@ -16,7 +13,12 @@ for ``--backend spmd``:
 Usage:
   STADI_HOST_DEVICES=4 PYTHONPATH=src python -m repro.launch.stadi_infer \
       --spmd --occupancies 0.0,0.5 --m-base 16 --m-warmup 4
+  PYTHONPATH=src python -m repro.launch.stadi_infer --arch sdxl-dit \
+      --use-pallas --m-base 20 --m-warmup 4 --verbose   # full width, TPU
 """
+from repro.hostenv import force_host_devices, use_compile_cache
+force_host_devices()
+use_compile_cache()                 # both before jax is imported
 
 import argparse
 import dataclasses

@@ -115,24 +115,25 @@ def nondegenerate_params(params, seed: int = 7):
     are exactly zero, so eps ignores attention (and hence the stale-KV
     buffers) entirely. Tests and benchmarks that probe staleness replace
     those zeros with small deterministic values so remote K/V genuinely
-    influences the trajectory. Returns a modified copy."""
+    influences the trajectory. Returns a modified copy; each replaced leaf
+    keeps its dtype, so a bf16 model stays bf16."""
+    def draw(key, scale, like):
+        return (scale * jax.random.normal(key, like.shape)).astype(like.dtype)
+
     params = dict(params)
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     blk = dict(params["blocks"])
-    blk["mod_w"] = 0.02 * jax.random.normal(ks[0], blk["mod_w"].shape)
-    blk["mod_b"] = 0.02 * jax.random.normal(ks[1], blk["mod_b"].shape)
+    blk["mod_w"] = draw(ks[0], 0.02, blk["mod_w"])
+    blk["mod_b"] = draw(ks[1], 0.02, blk["mod_b"])
     params["blocks"] = blk
-    params["final_mod_w"] = 0.02 * jax.random.normal(
-        ks[2], params["final_mod_w"].shape)
-    params["final_proj"] = 0.05 * jax.random.normal(
-        ks[3], params["final_proj"].shape)
+    params["final_mod_w"] = draw(ks[2], 0.02, params["final_mod_w"])
+    params["final_proj"] = draw(ks[3], 0.05, params["final_proj"])
     if "xo" in blk:
         # prompt cross-attention out-projection is adaLN-zero too; give it
         # a deterministic value so prompts genuinely steer the trajectory.
         # Drawn from a distinct key stream so class-conditional params stay
         # bitwise what they were pre-§17.
-        kx = jax.random.PRNGKey(seed + 101)
-        blk["xo"] = 0.05 * jax.random.normal(kx, blk["xo"].shape)
+        blk["xo"] = draw(jax.random.PRNGKey(seed + 101), 0.05, blk["xo"])
     return params
 
 

@@ -1160,9 +1160,11 @@ class DiffusionServingEngine:
         return jnp.stack([self.active[s].cond for s in idx])
 
     def _scatter(self, idx: np.ndarray, xs, ks, vs) -> None:
+        # the lanes' K/V comes out in the activation dtype (f32 latents
+        # promote a bf16 model's activations); the buffers keep cfg.dtype
         self._x = self._x.at[idx].set(xs)
-        self._pub_k = self._pub_k.at[idx].set(ks)
-        self._pub_v = self._pub_v.at[idx].set(vs)
+        self._pub_k = self._pub_k.at[idx].set(ks.astype(self._pub_k.dtype))
+        self._pub_v = self._pub_v.at[idx].set(vs.astype(self._pub_v.dtype))
 
     def _lane_bucket(self, slot: int) -> int:
         """The lane's prompt length bucket (0 for class-conditional

@@ -133,8 +133,7 @@ def param_specs(params: Any, mesh: Mesh, cfg=None):
 # ----------------------------------------------------------------------
 
 def batch_axes(mesh: Mesh):
-    # bare string (not a 1-tuple) so PartitionSpec equality is stable across
-    # jax versions: 0.4.x does not normalize P(("data",)) to P("data")
+    # bare string, not a 1-tuple: the specs compare equal to P("data")
     return ("pod", "data") if "pod" in mesh.shape else "data"
 
 

@@ -43,7 +43,8 @@ def _gather_fns(sizes):
     def f_bc(xl):
         return comm.uneven_all_gather_broadcast(xl[0], sizes, "dev")
 
-    return tuple(jax.jit(comm.shard_map_compat(f, mesh, P("dev"), P(None)))
+    return tuple(jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("dev"),
+                                      out_specs=P(None), check_vma=False))
                  for f in (f_pad, f_bc))
 
 

@@ -30,7 +30,6 @@ def test_uneven_all_gather_equivalence():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core import comm
-        from repro.core.comm import shard_map_compat
         devs = jax.devices(); N = len(devs)
         mesh = Mesh(np.asarray(devs), ('dev',))
         sizes = [3, 1, 4, 2, 5, 1, 2, 6][:N]
@@ -46,8 +45,9 @@ def test_uneven_all_gather_equivalence():
         def f_bc(xl):
             return comm.uneven_all_gather_broadcast(xl[0], sizes, 'dev')
         for f in (f_pad, f_bc):
-            got = np.asarray(jax.jit(shard_map_compat(
-                f, mesh, P('dev'), P(None)))(x))
+            got = np.asarray(jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=P('dev'), out_specs=P(None),
+                check_vma=False))(x))
             np.testing.assert_allclose(got, oracle, rtol=1e-6)
         print('COMM_OK')
     """)

@@ -8,17 +8,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.configs import get_config
 from repro.sharding import specs as sh
 
-def _mesh(sizes, names):
-    """AbstractMesh compat: jax >= 0.5 takes (sizes, names), 0.4.x takes
-    ((name, size), ...)."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _mesh((16, 16), ("data", "model"))
-MESH3 = _mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs_for(arch):
