@@ -1,0 +1,7 @@
+"""Model step: useful FLOPs of the executed plan times the images served in the window (padded lanes not counted),
+over the window times the chip's bf16 peak, in %."""
+from bench import flops
+
+
+def read(run):
+    return flops.mfu_percent(run)
