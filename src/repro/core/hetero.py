@@ -5,8 +5,7 @@ simulation (paper §V-A "Occupancy Simulation"), and online re-profiling
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Callable, List, Optional, Sequence
+from typing import List, Optional, Sequence
 
 from repro.core.schedule import effective_speed
 
@@ -84,19 +83,8 @@ def stage_partition(n_blocks: int, speeds: Sequence[float]) -> List[int]:
 
 
 # ----------------------------------------------------------------------
-# profiling
+# online re-profiling
 # ----------------------------------------------------------------------
-
-def profile_step_time(step_fn: Callable[[], None], warmup: int = 1,
-                      iters: int = 3) -> float:
-    """Wall-clock a single-step callable (used to calibrate the simulator)."""
-    for _ in range(warmup):
-        step_fn()
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        step_fn()
-    return (time.perf_counter() - t0) / iters
-
 
 class OnlineProfiler:
     """Beyond-paper: EWMA re-estimation of v_i from measured per-interval

@@ -23,6 +23,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.diffusion import DiTConfig
 from repro.core import buffers as buf_lib
 from repro.core import comm as comm_lib
@@ -191,6 +192,7 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
     in ``spmd_seq``.
     """
     p = cfg.patch_size
+    P = cfg.tokens_per_side              # token rows of the whole image
     M_base = plan.m_base
     plan0, patches0 = plan, list(patches)  # trace provenance: the initial
     # allocation; per-interval events record what actually executed
@@ -237,12 +239,18 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
 
         if isinstance(ev, ir.Warmup):
             # synchronous step == exact full forward on every worker
-            eps, kvs = _full_step(ts[ev.fine_step])
-            x = sampler_lib.ddim_step(sched, x, eps, ts[ev.fine_step],
-                                      ts[ev.fine_step + 1])
-            published = buf_lib.Published(kvs[0], kvs[1], ev.fine_step)
-            read_pub = published
-            records.append(ir.warmup_record(ev))
+            with obs.span("exec.warmup"):
+                with obs.span("exec.buffers"):
+                    t = ts[ev.fine_step]
+                with obs.span("exec.model", rows=P):
+                    eps, kvs = _full_step(t)
+                with obs.span("exec.buffers"):
+                    t_from, t_to = ts[ev.fine_step], ts[ev.fine_step + 1]
+                with obs.span("exec.sampler"):
+                    x = sampler_lib.ddim_step(sched, x, eps, t_from, t_to)
+                published = buf_lib.Published(kvs[0], kvs[1], ev.fine_step)
+                read_pub = published
+                records.append(ir.warmup_record(ev))
 
         elif isinstance(ev, ir.GuidanceExchange):
             fresh = ev.fresh             # verdict for the coming interval
@@ -253,58 +261,70 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
             seq_hops = ev.hops
 
         elif isinstance(ev, ir.ComputeInterval):
-            if published is None:        # M_w == 0: bootstrap buffers once
-                _, kvs = _full_step(ts[0])
-                published = buf_lib.Published(kvs[0], kvs[1], -1)
-                read_pub = published
-            interval = ev
-            bounds_tok = patch_bounds(ev.patches)
-            bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
-            pending = {}
-            new_slabs = {}
-            for i in ev.workers:
-                r = ev.ratios[i]
-                x_loc = _slab(x, bounds_lat[i])
-                tok_lo = bounds_tok[i][0] * cfg.tokens_per_side
-                for s in range(ev.substeps[i]):
-                    t_from = ts[ev.fine_step + s * r]
-                    t_to = ts[ev.fine_step + (s + 1) * r]
-                    if not guided:
-                        eps, kvs = _jit_patch_step(
-                            params, cfg, x_loc, t_from, cond,
-                            bounds_tok[i][0], read_pub.k, read_pub.v)
-                    else:
-                        eps, kvs = guided_substep(
-                            params, cfg, x_loc, t_from, cond,
-                            bounds_tok[i][0], read_pub, published,
-                            guidance, fresh, ucache, i, first=(s == 0))
-                    x_loc = sampler_lib.ddim_step(sched, x_loc, eps,
-                                                  t_from, t_to)
-                    if s == 0:   # Alg.1 l.16-17 / l.23: publish at interval start
-                        buf_lib.publish_local(pending, i, kvs[0], kvs[1],
-                                              tok_lo)
-                new_slabs[i] = x_loc
+            with obs.span("exec.interval"):
+                if published is None:    # M_w == 0: bootstrap buffers once
+                    with obs.span("exec.buffers"):
+                        t = ts[0]
+                    with obs.span("exec.model", rows=P):
+                        _, kvs = _full_step(t)
+                    published = buf_lib.Published(kvs[0], kvs[1], -1)
+                    read_pub = published
+                interval = ev
+                bounds_tok = patch_bounds(ev.patches)
+                bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
+                pending = {}
+                new_slabs = {}
+                for i in ev.workers:
+                    r = ev.ratios[i]
+                    with obs.span("exec.buffers"):
+                        x_loc = _slab(x, bounds_lat[i])
+                    tok_lo = bounds_tok[i][0] * cfg.tokens_per_side
+                    for s in range(ev.substeps[i]):
+                        with obs.span("exec.buffers"):
+                            t_from = ts[ev.fine_step + s * r]
+                            t_to = ts[ev.fine_step + (s + 1) * r]
+                        with obs.span("exec.model", rows=ev.patches[i]):
+                            if not guided:
+                                eps, kvs = _jit_patch_step(
+                                    params, cfg, x_loc, t_from, cond,
+                                    bounds_tok[i][0], read_pub.k, read_pub.v)
+                            else:
+                                eps, kvs = guided_substep(
+                                    params, cfg, x_loc, t_from, cond,
+                                    bounds_tok[i][0], read_pub, published,
+                                    guidance, fresh, ucache, i,
+                                    first=(s == 0))
+                        with obs.span("exec.sampler"):
+                            x_loc = sampler_lib.ddim_step(sched, x_loc, eps,
+                                                          t_from, t_to)
+                        # Alg.1 l.16-17 / l.23: publish at interval start
+                        if s == 0:
+                            with obs.span("exec.buffers"):
+                                buf_lib.publish_local(pending, i, kvs[0],
+                                                      kvs[1], tok_lo)
+                    new_slabs[i] = x_loc
 
         elif isinstance(ev, ir.Exchange):
             # every worker's slab write-back is local memory (disjoint rows);
             # the policy only gates the REMOTE traffic: K/V merge + gather
-            bounds_lat = [(a * p, b * p) for a, b in
-                          patch_bounds(ev.patches)]
-            for i in interval.workers:
-                lat = bounds_lat[i]
-                x = x.at[:, lat[0]:lat[1]].set(new_slabs[i])
-            if ev.kind == "full":
-                prev_published = published
-                published = buf_lib.merge(published, pending, ev.fine_step,
-                                          axis=tok_axis)
-                read_pub = published
-            elif ev.kind == "skip":
-                read_pub = published     # stale: pending never broadcast
-            elif ev.kind == "predict":
-                read_pub = buf_lib.extrapolate(prev_published, published,
-                                               ev.fine_step)
-            rec = ir.record(interval, ev.kind, uncond_fresh=fresh,
-                            seq_hops=seq_hops)
+            with obs.span("exec.exchange"):
+                bounds_lat = [(a * p, b * p) for a, b in
+                              patch_bounds(ev.patches)]
+                for i in interval.workers:
+                    lat = bounds_lat[i]
+                    x = x.at[:, lat[0]:lat[1]].set(new_slabs[i])
+                if ev.kind == "full":
+                    prev_published = published
+                    published = buf_lib.merge(published, pending,
+                                              ev.fine_step, axis=tok_axis)
+                    read_pub = published
+                elif ev.kind == "skip":
+                    read_pub = published  # stale: pending never broadcast
+                elif ev.kind == "predict":
+                    read_pub = buf_lib.extrapolate(prev_published, published,
+                                                   ev.fine_step)
+                rec = ir.record(interval, ev.kind, uncond_fresh=fresh,
+                                seq_hops=seq_hops)
             fresh = True
             records.append(rec)
             if interval_hook is not None and ev.fine_step < M_base:
@@ -315,8 +335,9 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
         # ir.Replan events need no numerics: the next ComputeInterval
         # already carries the new patches/ratios
 
-    trace = ir.make_trace(records, plan0, patches0, cfg, int(B),
-                          guidance=guidance, seq=seq)
+    with obs.span("exec.record"):
+        trace = ir.make_trace(records, plan0, patches0, cfg, int(B),
+                              guidance=guidance, seq=seq)
     return RunResult(x, trace)
 
 

@@ -49,6 +49,7 @@ import os
 import warnings
 from typing import Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
+from repro import obs
 from repro.configs.diffusion import DiTConfig
 from repro.core import hetero
 from repro.core import patch_parallel as pp
@@ -974,30 +975,31 @@ class StadiPipeline:
         config knobs in this one pass. With a plan cache configured, the persistent cache
         is consulted before any planner search (``use_cache=False`` forces
         a live search without touching the cache)."""
-        speeds = list(speeds) if speeds is not None else self.config.speeds
-        knobs = self._plan_knobs()
-        key = None
-        if self.plan_cache is not None and use_cache:
-            key = self.plan_cache.signature(speeds, self._model_key(),
-                                            self._workload_key(knobs))
-            hit = self.plan_cache.get(key)
-            if hit is not None:
+        with obs.span("stadi.plan"):
+            speeds = list(speeds) if speeds is not None else self.config.speeds
+            knobs = self._plan_knobs()
+            key = None
+            if self.plan_cache is not None and use_cache:
+                key = self.plan_cache.signature(speeds, self._model_key(),
+                                                self._workload_key(knobs))
+                hit = self.plan_cache.get(key)
+                if hit is not None:
+                    self.last_plan_key = key
+                    return hit
+            raw = get_planner(self.config.planner)(speeds, knobs, self.p_total)
+            self.planner_calls += 1
+            plan = dataclasses.replace(
+                raw,
+                stages=_resolve_stages(raw, self.model_cfg, knobs),
+                guidance=_resolve_guidance(raw, knobs),
+                seq=(raw.seq if raw.seq is not None
+                     else _resolve_seq(raw, self.model_cfg, knobs)),
+                frames=(raw.frames if raw.frames is not None
+                        else _resolve_frames(raw, knobs)))
+            if key is not None:
+                self.plan_cache.put(key, plan)
                 self.last_plan_key = key
-                return hit
-        raw = get_planner(self.config.planner)(speeds, knobs, self.p_total)
-        self.planner_calls += 1
-        plan = dataclasses.replace(
-            raw,
-            stages=_resolve_stages(raw, self.model_cfg, knobs),
-            guidance=_resolve_guidance(raw, knobs),
-            seq=(raw.seq if raw.seq is not None
-                 else _resolve_seq(raw, self.model_cfg, knobs)),
-            frames=(raw.frames if raw.frames is not None
-                    else _resolve_frames(raw, knobs)))
-        if key is not None:
-            self.plan_cache.put(key, plan)
-            self.last_plan_key = key
-        return plan
+            return plan
 
     def generate(self, x_T=None, cond=None, *,
                  measured_speeds: Optional[Sequence[float]] = None
@@ -1009,39 +1011,46 @@ class StadiPipeline:
         planned speeds and ``rebalance_every`` is on, the profiler detects it
         and the remaining steps are re-planned mid-run.
         """
-        config = self.config
-        plan = self.plan()
-        check_backend_can_run(plan, config)
-        replans: List[ReplanEvent] = []
-        hook = None
-        if config.rebalance_every > 0:
-            if config.backend != "emulated":
-                raise ValueError("rebalance_every requires the 'emulated' "
-                                 f"backend, not {config.backend!r}")
-            hook = self._make_rebalance_hook(plan, measured_speeds, replans)
-        # ONE normalized call shape for every backend (EXECUTOR_KWARGS):
-        # strictly keyword, so per-backend kwarg drift cannot creep back in
-        from repro.kernels import ops as kops
-        kstats_before = kops.kernel_stats_snapshot()
-        image, trace = get_executor(config.backend)(
-            params=self.params, model_cfg=self.model_cfg, sched=self.sched,
-            x_T=x_T, cond=cond, plan=plan, config=config,
-            interval_hook=hook)
-        kernel_stats = kops.kernel_stats_delta(
-            kstats_before, kops.kernel_stats_snapshot())
-        for bucket, counts in kernel_stats.items():
-            for key, n in counts.items():
-                self.kernel_stats[bucket][key] = (
-                    self.kernel_stats[bucket].get(key, 0) + n)
-        latency = None
-        if config.cost_model is not None:
-            lat_speeds = (list(measured_speeds) if measured_speeds is not None
-                          else config.speeds)
-            latency = sim.simulate_trace(trace, lat_speeds, config.cost_model)
-        elif config.backend == "simulate":
-            raise ValueError("the 'simulate' backend needs config.cost_model")
-        return PipelineResult(image, trace, plan, latency, replans,
-                              kernel_stats)
+        with obs.span("stadi.generate"):
+            config = self.config
+            plan = self.plan()
+            check_backend_can_run(plan, config)
+            replans: List[ReplanEvent] = []
+            hook = None
+            if config.rebalance_every > 0:
+                if config.backend != "emulated":
+                    raise ValueError("rebalance_every requires the "
+                                     "'emulated' backend, not "
+                                     f"{config.backend!r}")
+                hook = self._make_rebalance_hook(plan, measured_speeds,
+                                                 replans)
+            # ONE normalized call shape for every backend (EXECUTOR_KWARGS):
+            # strictly keyword, so per-backend kwarg drift cannot creep in
+            from repro.kernels import ops as kops
+            kstats_before = kops.kernel_stats_snapshot()
+            image, trace = get_executor(config.backend)(
+                params=self.params, model_cfg=self.model_cfg,
+                sched=self.sched,
+                x_T=x_T, cond=cond, plan=plan, config=config,
+                interval_hook=hook)
+            kernel_stats = kops.kernel_stats_delta(
+                kstats_before, kops.kernel_stats_snapshot())
+            for bucket, counts in kernel_stats.items():
+                for key, n in counts.items():
+                    self.kernel_stats[bucket][key] = (
+                        self.kernel_stats[bucket].get(key, 0) + n)
+            latency = None
+            if config.cost_model is not None:
+                lat_speeds = (list(measured_speeds)
+                              if measured_speeds is not None
+                              else config.speeds)
+                latency = sim.simulate_trace(trace, lat_speeds,
+                                             config.cost_model)
+            elif config.backend == "simulate":
+                raise ValueError("the 'simulate' backend needs "
+                                 "config.cost_model")
+            return PipelineResult(image, trace, plan, latency, replans,
+                                  kernel_stats)
 
     def generate_many(self, x_Ts: Sequence, conds: Sequence, *,
                       slots: int = 4) -> List[PipelineResult]:

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import buffers as buf_lib
 from repro.core import comm as comm_lib
 from repro.core import events as ir
@@ -208,21 +209,29 @@ class _VmapWarmupMixin:
 
     def _warmup_finish(self, xs, t_from, t_to, eps, ks, vs):
         shape = (xs.shape[0],) + (1,) * (xs.ndim - 1)
-        xs = sampler_lib.ddim_step(self.sched, xs, eps,
-                                   t_from.reshape(shape), t_to.reshape(shape))
+        with obs.span("exec.sampler"):
+            xs = sampler_lib.ddim_step(self.sched, xs, eps,
+                                       t_from.reshape(shape),
+                                       t_to.reshape(shape))
         return xs, ks, vs
+
+    def _model_span(self):
+        """The ``exec.model`` span of a full-image dispatch."""
+        return obs.span("exec.model", rows=self.model_cfg.tokens_per_side)
 
     def warmup_step(self, xs, t_from, t_to, conds):
         """One synchronous fine step per lane: returns (xs', ks, vs)."""
-        eps, (ks, vs) = _vmap_full_step(self.params, self.model_cfg, xs,
-                                        t_from, conds)
+        with self._model_span():
+            eps, (ks, vs) = _vmap_full_step(self.params, self.model_cfg, xs,
+                                            t_from, conds)
         return self._warmup_finish(xs, t_from, t_to, eps, ks, vs)
 
     def warmup_step_guided(self, xs, t_from, t_to, conds, scales):
         """Guided synchronous step per lane: returns (xs', k2s, v2s) with
         branch-stacked fresh K/V [G,2,L,1,N,H,hd]."""
-        eps, (k2s, v2s) = _vmap_guided_full_step(self.params, self.model_cfg,
-                                                 xs, t_from, conds, scales)
+        with self._model_span():
+            eps, (k2s, v2s) = _vmap_guided_full_step(
+                self.params, self.model_cfg, xs, t_from, conds, scales)
         return self._warmup_finish(xs, t_from, t_to, eps, k2s, v2s)
 
 
@@ -264,30 +273,35 @@ class EmulatedStepper(_VmapWarmupMixin):
         for i in workers:
             r = plan.ratios[i]
             lo, hi = bounds_lat[i]
-            x_loc = xs[:, :, lo:hi]
+            with obs.span("exec.buffers"):
+                x_loc = xs[:, :, lo:hi]
             for s in range(R // r):
-                t_from = self._ts[fine0 + s * r]
-                t_to = self._ts[fine0 + (s + 1) * r]
-                eps, (k, v) = step_fn(x_loc, t_from, bounds_tok[i][0])
-                x_loc = sampler_lib.ddim_step(self.sched, x_loc, eps,
-                                              t_from.reshape(tshape),
-                                              t_to.reshape(tshape))
+                with obs.span("exec.buffers"):
+                    t_from = self._ts[fine0 + s * r]
+                    t_to = self._ts[fine0 + (s + 1) * r]
+                with obs.span("exec.model", rows=self.plan.patches[i]):
+                    eps, (k, v) = step_fn(x_loc, t_from, bounds_tok[i][0])
+                with obs.span("exec.sampler"):
+                    x_loc = sampler_lib.ddim_step(self.sched, x_loc, eps,
+                                                  t_from.reshape(tshape),
+                                                  t_to.reshape(tshape))
                 if s == 0:           # Alg.1: publish the first substep's KV
                     pending[i] = (k, v)
             new_slabs[i] = x_loc
         # interval boundary: all-gather of x + buffer merge (same order as
         # buffers.merge: ascending worker id)
-        for i in workers:
-            lo, hi = bounds_lat[i]
-            xs = xs.at[:, :, lo:hi].set(new_slabs[i])
-        if merge:
-            for i in sorted(pending):
-                k, v = pending[i]
-                start = bounds_tok[i][0] * cfg.tokens_per_side
-                pub_k = jax.lax.dynamic_update_slice_in_dim(
-                    pub_k, k.astype(pub_k.dtype), start, axis=tok_axis)
-                pub_v = jax.lax.dynamic_update_slice_in_dim(
-                    pub_v, v.astype(pub_v.dtype), start, axis=tok_axis)
+        with obs.span("exec.buffers"):
+            for i in workers:
+                lo, hi = bounds_lat[i]
+                xs = xs.at[:, :, lo:hi].set(new_slabs[i])
+            if merge:
+                for i in sorted(pending):
+                    k, v = pending[i]
+                    start = bounds_tok[i][0] * cfg.tokens_per_side
+                    pub_k = jax.lax.dynamic_update_slice_in_dim(
+                        pub_k, k.astype(pub_k.dtype), start, axis=tok_axis)
+                    pub_v = jax.lax.dynamic_update_slice_in_dim(
+                        pub_v, v.astype(pub_v.dtype), start, axis=tok_axis)
         return xs, pub_k, pub_v
 
     def interval(self, xs, fine0, conds, pub_k, pub_v, merge: bool = True):
@@ -946,58 +960,83 @@ class DiffusionServingEngine:
         """One round: admit -> warmup group -> adaptive group(s) -> retire."""
         report = RoundReport(index=len(self.rounds))
         wall0 = time.perf_counter()
-        if self.frames is not None:
-            return self._frames_round(report, wall0)
-        if self._pending_plan is not None:
-            self._try_install_pending()
-        self._admit(report)
-        temporal = self.plan.temporal
-        M_w, M_base, R = temporal.m_warmup, temporal.m_base, temporal.lcm
-        warm = sorted(s for s, r in self.active.items()
-                      if r.fine_step < M_w)
-        adapt = sorted(s for s, r in self.active.items()
-                       if r.fine_step >= M_w)
-        report.warmup_lanes, report.adaptive_lanes = warm, adapt
+        with obs.span("engine.round"):
+            if self.frames is not None:
+                return self._frames_round(report, wall0)
+            if self._pending_plan is not None:
+                with obs.span("engine.cost_model"):
+                    self._try_install_pending()
+            with obs.span("engine.admit"):
+                self._admit(report)
+            M_w = self.plan.temporal.m_warmup
+            warm = sorted(s for s, r in self.active.items()
+                          if r.fine_step < M_w)
+            adapt = sorted(s for s, r in self.active.items()
+                           if r.fine_step >= M_w)
+            report.warmup_lanes, report.adaptive_lanes = warm, adapt
+            self._warmup_phase(report, warm)
+            if adapt:
+                self._adaptive_phase(report, adapt)
+            self.modeled_clock_s += report.modeled_s
+            return self._retire(report, wall0)
 
+    def _warmup_phase(self, report: RoundReport, warm: List[int]) -> None:
+        """One synchronous fine step for every warm-up lane, one batched
+        dispatch per (guided, bucket) batch."""
         for guided, bucket, lanes in self._by_guided(warm):
-            idx = self._pad(lanes)
-            fine = np.asarray([self.active[s].fine_step for s in idx])
+            with obs.span("engine.lanes"):
+                idx = self._pad(lanes)
+                fine = np.asarray([self.active[s].fine_step for s in idx])
+                args = (self._x[idx], self._ts[fine], self._ts[fine + 1],
+                        self._conds(idx))
+                if guided:
+                    args += (jnp.asarray(self._scales[idx]),)
+            with self._group_span("exec.warmup", lanes, idx):
+                out = (self.stepper.warmup_step_guided(*args) if guided
+                       else self.stepper.warmup_step(*args))
             if guided:
-                xs, k2s, v2s = self.stepper.warmup_step_guided(
-                    self._x[idx], self._ts[fine], self._ts[fine + 1],
-                    self._conds(idx), jnp.asarray(self._scales[idx]))
-                self._x = self._x.at[idx].set(xs)
-                self._gk = self._gk.at[idx].set(k2s)
-                self._gv = self._gv.at[idx].set(v2s)
+                xs, k2s, v2s = out
+                with obs.span("engine.lanes"):
+                    self._x = self._x.at[idx].set(xs)
+                    self._gk = self._gk.at[idx].set(k2s)
+                    self._gv = self._gv.at[idx].set(v2s)
             else:
-                xs, ks, vs = self.stepper.warmup_step(
-                    self._x[idx], self._ts[fine], self._ts[fine + 1],
-                    self._conds(idx))
-                self._scatter(idx, xs, ks, vs)
+                with obs.span("engine.lanes"):
+                    self._scatter(idx, *out)
             for s in lanes:
                 self.active[s].fine_step += 1
-            _, cost = self._phase_cost(len(lanes), warm=True, guided=guided,
-                                       cond_tokens=bucket)
+            with obs.span("engine.cost_model"):
+                _, cost = self._phase_cost(len(lanes), warm=True,
+                                           guided=guided,
+                                           cond_tokens=bucket)
             report.modeled_s += cost
 
-        if adapt:
-            placement = None
-            wants_ctx = getattr(self.stepper, "wants_ctx", False)
-            for group, (read_factor, trail_kind, fill, seq_hops,
-                        guided, bucket) in self._groups(adapt):
-                idx = self._pad(group)
-                fine = np.asarray([self.active[s].fine_step for s in idx])
-                merge = trail_kind == "full"
-                if guided:           # branch-stacked per-lane CFG state
+    def _adaptive_phase(self, report: RoundReport, adapt: List[int]) -> None:
+        """One adaptive interval (plan.lcm fine steps) for every adaptive
+        lane, one batched interval per lane group."""
+        R = self.plan.temporal.lcm
+        placement = None
+        wants_ctx = getattr(self.stepper, "wants_ctx", False)
+        for group, (read_factor, trail_kind, fill, seq_hops,
+                    guided, bucket) in self._groups(adapt):
+            merge = trail_kind == "full"
+            if guided:               # branch-stacked per-lane CFG state
+                with obs.span("engine.lanes"):
+                    idx = self._pad(group)
+                    fine = np.asarray([self.active[s].fine_step
+                                       for s in idx])
                     bk, bv = self._gk[idx], self._gv[idx]
                     if read_factor:
                         bk = buf_lib.extrapolate_arrays(
                             bk, self._prev_gk[idx], read_factor)
                         bv = buf_lib.extrapolate_arrays(
                             bv, self._prev_gv[idx], read_factor)
-                    xs, ks, vs = self.stepper.interval_guided(
-                        self._x[idx], fine, self._conds(idx),
-                        jnp.asarray(self._scales[idx]), bk, bv, merge=merge)
+                    args = (self._x[idx], fine, self._conds(idx),
+                            jnp.asarray(self._scales[idx]), bk, bv)
+                with self._group_span("exec.interval", group, idx):
+                    xs, ks, vs = self.stepper.interval_guided(*args,
+                                                              merge=merge)
+                with obs.span("engine.lanes"):
                     self._x = self._x.at[idx].set(xs)
                     if merge:
                         if self._track_prev:
@@ -1007,14 +1046,18 @@ class DiffusionServingEngine:
                                 self._gv[idx])
                         self._gk = self._gk.at[idx].set(ks)
                         self._gv = self._gv.at[idx].set(vs)
-                    for s in group:
-                        self.active[s].fine_step += R
+                for s in group:
+                    self.active[s].fine_step += R
+                with obs.span("engine.cost_model"):
                     placement, cost = self._phase_cost(
                         len(group), warm=False, kind=trail_kind, fill=fill,
                         guided=True, seq_hops=seq_hops, cond_tokens=bucket)
-                    report.modeled_s += cost
-                    report.exchange_kinds.append(trail_kind)
-                    continue
+                report.modeled_s += cost
+                report.exchange_kinds.append(trail_kind)
+                continue
+            with obs.span("engine.lanes"):
+                idx = self._pad(group)
+                fine = np.asarray([self.active[s].fine_step for s in idx])
                 bk, bv = self._pub_k[idx], self._pub_v[idx]
                 # predictive boundary before this group — staged steppers
                 # never read the extrapolation (ctx subsumes it), so skip
@@ -1023,22 +1066,22 @@ class DiffusionServingEngine:
                                                     read_factor)
                     bv = buf_lib.extrapolate_arrays(bv, self._prev_v[idx],
                                                     read_factor)
+                if wants_ctx and fill:   # pipe refill: contexts <- published
+                    self._ctx_k = self._ctx_k.at[idx].set(self._pub_k[idx])
+                    self._ctx_v = self._ctx_v.at[idx].set(self._pub_v[idx])
+                args = (self._x[idx], fine, self._conds(idx), bk, bv)
                 if wants_ctx:
-                    if fill:         # pipe refill: contexts <- published
-                        self._ctx_k = self._ctx_k.at[idx].set(
-                            self._pub_k[idx])
-                        self._ctx_v = self._ctx_v.at[idx].set(
-                            self._pub_v[idx])
+                    args += (self._ctx_k[idx], self._ctx_v[idx])
+            with self._group_span("exec.interval", group, idx):
+                if wants_ctx:
                     xs, ks, vs, ck, cv = self.stepper.interval_ctx(
-                        self._x[idx], fine, self._conds(idx), bk, bv,
-                        self._ctx_k[idx], self._ctx_v[idx],
-                        merge=merge)
+                        *args, merge=merge)
+                else:
+                    xs, ks, vs = self.stepper.interval(*args, merge=merge)
+            with obs.span("engine.lanes"):
+                if wants_ctx:
                     self._ctx_k = self._ctx_k.at[idx].set(ck)
                     self._ctx_v = self._ctx_v.at[idx].set(cv)
-                else:
-                    xs, ks, vs = self.stepper.interval(
-                        self._x[idx], fine, self._conds(idx), bk, bv,
-                        merge=merge)
                 self._x = self._x.at[idx].set(xs)
                 if merge:
                     if self._track_prev:
@@ -1049,17 +1092,19 @@ class DiffusionServingEngine:
                             self._pub_v[idx])
                     self._pub_k = self._pub_k.at[idx].set(ks)
                     self._pub_v = self._pub_v.at[idx].set(vs)
-                for s in group:
-                    self.active[s].fine_step += R
+            for s in group:
+                self.active[s].fine_step += R
+            with obs.span("engine.cost_model"):
                 placement, cost = self._phase_cost(len(group), warm=False,
                                                    kind=trail_kind,
                                                    fill=fill,
                                                    seq_hops=seq_hops,
                                                    cond_tokens=bucket)
-                report.modeled_s += cost
-                report.exchange_kinds.append(trail_kind)
-            report.placement = placement
-            if self.profiler is not None:
+            report.modeled_s += cost
+            report.exchange_kinds.append(trail_kind)
+        report.placement = placement
+        if self.profiler is not None:
+            with obs.span("engine.cost_model"):
                 self._feed_profiler()
                 self._rounds_since_check += 1
                 if (self._rounds_since_check >= self.rebalance_every
@@ -1067,21 +1112,33 @@ class DiffusionServingEngine:
                     self._rounds_since_check = 0
                     self._maybe_replan()
 
-        self.modeled_clock_s += report.modeled_s
-        done_slots = [s for s, r in sorted(self.active.items())
-                      if r.fine_step >= M_base]
-        if done_slots:           # flush async dispatch BEFORE stamping wall
-            jax.block_until_ready(self._x)
-        finished = []
-        for slot in done_slots:
-            req = self.active.pop(slot)
-            req.image = self._x[slot]
-            req.done = True
-            req.finish_round = report.index
-            req.modeled_latency_s = self.modeled_clock_s - req.submit_clock_s
-            req.wall_latency_s = time.perf_counter() - req._submit_wall
-            finished.append(req)
-        self.completed.extend(finished)
+    @staticmethod
+    def _group_span(name: str, lanes: Sequence[int], idx: np.ndarray):
+        """The span of one stepper call over a lane group: ``lanes`` real
+        lanes, padded to ``len(idx)`` (the profiler reads the padding)."""
+        return obs.span(name, lanes=len(lanes), padded=len(idx) - len(lanes))
+
+    def _retire(self, report: RoundReport,
+                wall0: float) -> List[DiffusionRequest]:
+        """Hand out the images of lanes that reached the last fine step and
+        free their slots; closes the round's report."""
+        M_base = self.plan.temporal.m_base
+        with obs.span("engine.retire"):
+            done_slots = [s for s, r in sorted(self.active.items())
+                          if r.fine_step >= M_base]
+            if done_slots:       # flush async dispatch BEFORE stamping wall
+                jax.block_until_ready(self._x)
+            finished = []
+            for slot in done_slots:
+                req = self.active.pop(slot)
+                req.image = self._x[slot]
+                req.done = True
+                req.finish_round = report.index
+                req.modeled_latency_s = (self.modeled_clock_s
+                                         - req.submit_clock_s)
+                req.wall_latency_s = time.perf_counter() - req._submit_wall
+                finished.append(req)
+            self.completed.extend(finished)
         report.wall_s = time.perf_counter() - wall0
         self.rounds.append(report)
         return finished
