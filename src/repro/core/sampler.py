@@ -43,6 +43,12 @@ class NoiseSchedule:
         return (1 - w) * self.alpha_bar[lo] + w * self.alpha_bar[hi]
 
 
+# a pytree, so that jitted steps take the schedule table as an argument
+jax.tree_util.register_dataclass(NoiseSchedule,
+                                 data_fields=["alpha_bar", "betas"],
+                                 meta_fields=["T"])
+
+
 def linear_schedule(T: int = 1000, beta_min: float = 1e-4, beta_max: float = 2e-2) -> NoiseSchedule:
     betas = jnp.concatenate([jnp.zeros((1,)), jnp.linspace(beta_min, beta_max, T)])
     alpha_bar = jnp.cumprod(1.0 - betas)
@@ -100,15 +106,38 @@ def cfg_apply_delta(eps_c, delta, scale):
 # ----------------------------------------------------------------------
 
 def ddim_step(sched: NoiseSchedule, x, eps, t_from, t_to):
-    """One Lemma-1 update from t_{m-1}=t_from to t_m=t_to (t_to < t_from)."""
+    """One Lemma-1 update from t_{m-1}=t_from to t_m=t_to (t_to < t_from).
+
+    Called eagerly, the update is one compiled program; inside traced code
+    (``lax.scan``, ``shard_map`` bodies) it is traced inline. The
+    timesteps are scalars or per-lane arrays over x's leading axes (``[G]``
+    against ``[G,1,H,W,C]``); they broadcast over x's remaining axes."""
+    args = (sched, x, eps, t_from, t_to)
+    if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
+        return _ddim_update(*args)
+    return _ddim_program(*args)
+
+
+def _ddim_update(sched, x, eps, t_from, t_to):
     a_from, a_to = sched.alpha(t_from), sched.alpha(t_to)
     s_from, s_to = sched.sigma(t_from), sched.sigma(t_to)
     # sigma_to * (e^{h} - 1) == a_to*s_from/a_from - s_to  exactly (VP param);
     # this form is finite at the t_to = 0 endpoint where lambda -> +inf.
     coef = a_to * s_from / a_from - s_to
+    ratio = a_to / a_from
+    lanes = coef.shape + (1,) * (x.ndim - coef.ndim)
     x32 = x.astype(jnp.float32)
-    out = (a_to / a_from) * x32 - coef * eps.astype(jnp.float32)
+    out = (ratio.reshape(lanes) * x32
+           - coef.reshape(lanes) * eps.astype(jnp.float32))
     return out.astype(x.dtype)
+
+
+# With XLA's fusion pass off every operation rounds to f32 as it does run
+# op by op: fused, the CPU backend contracts a product and the difference
+# that takes it into one FMA, an ULP away. (The TPU compiler names its
+# passes otherwise and fuses as before.)
+_ddim_program = jax.jit(_ddim_update,
+                        compiler_options={"xla_disable_hlo_passes": "fusion"})
 
 
 def ddpm_step(sched: NoiseSchedule, x, eps, t, noise):

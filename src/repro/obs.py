@@ -28,7 +28,7 @@ SPANS = (
     "exec.interval",     # one adaptive interval over all workers; in the
                          # engine of a lane group: lanes, padded
     "exec.model",        # one jitted denoiser dispatch; rows (token rows)
-    "exec.sampler",      # one DDIM update (eager arithmetic on the host)
+    "exec.sampler",      # one DDIM update (one compiled program)
     "exec.buffers",      # slab slices and write-backs, timestep reads, K/V
     "exec.exchange",     # an interval boundary: slab write-back, K/V merge
     "exec.record",       # the execution trace built at the end of a run

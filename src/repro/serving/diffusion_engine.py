@@ -19,12 +19,12 @@ slot-major stacked arrays, so a batched step is a gather / one vmapped
 denoiser dispatch / scatter.
 
 Numerics: the "emulated" stepper mirrors ``patch_parallel.run_schedule``
-call-for-call — same jit boundaries, eager DDIM updates, publish-at-first-
-substep and merge-at-interval-boundary buffer semantics — and vmap lanes are
-computed independently, so every request's final image is **bitwise
-identical** to a single-request ``pipe.generate`` (tested). The "spmd"
-stepper instead shard_maps each interval across ``jax.devices()`` for
-cohorts of requests that share a fine-step position.
+call-for-call — same jit boundaries, the same compiled DDIM update,
+publish-at-first-substep and merge-at-interval-boundary buffer semantics —
+and vmap lanes are computed independently, so every request's final
+image is **bitwise identical** to a single-request ``pipe.generate``
+(tested). The "spmd" stepper instead shard_maps each interval across
+``jax.devices()`` for cohorts of requests that share a fine-step position.
 
 Latency: every round is costed against ``StadiConfig.cluster`` with the
 ``simulate`` cost model — per-round device placement assigns the heaviest
@@ -208,11 +208,8 @@ class _VmapWarmupMixin:
         self.sched = sched
 
     def _warmup_finish(self, xs, t_from, t_to, eps, ks, vs):
-        shape = (xs.shape[0],) + (1,) * (xs.ndim - 1)
         with obs.span("exec.sampler"):
-            xs = sampler_lib.ddim_step(self.sched, xs, eps,
-                                       t_from.reshape(shape),
-                                       t_to.reshape(shape))
+            xs = sampler_lib.ddim_step(self.sched, xs, eps, t_from, t_to)
         return xs, ks, vs
 
     def _model_span(self):
@@ -262,12 +259,10 @@ class EmulatedStepper(_VmapWarmupMixin):
         in ascending worker order — mirroring ``buffers.merge``."""
         plan, cfg = self.plan.temporal, self.model_cfg
         R, p = plan.lcm, cfg.patch_size
-        G = xs.shape[0]
         fine0 = np.asarray(fine0)
         bounds_tok = patch_bounds(self.plan.patches)
         bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
         workers = [i for i in plan.active if self.plan.patches[i] > 0]
-        tshape = (G,) + (1,) * (xs.ndim - 1)
 
         pending, new_slabs = {}, {}
         for i in workers:
@@ -283,8 +278,7 @@ class EmulatedStepper(_VmapWarmupMixin):
                     eps, (k, v) = step_fn(x_loc, t_from, bounds_tok[i][0])
                 with obs.span("exec.sampler"):
                     x_loc = sampler_lib.ddim_step(self.sched, x_loc, eps,
-                                                  t_from.reshape(tshape),
-                                                  t_to.reshape(tshape))
+                                                  t_from, t_to)
                 if s == 0:           # Alg.1: publish the first substep's KV
                     pending[i] = (k, v)
             new_slabs[i] = x_loc
@@ -378,12 +372,10 @@ class PipefuseStepper(EmulatedStepper):
         """
         plan, cfg = self.plan.temporal, self.model_cfg
         R, p = plan.lcm, cfg.patch_size
-        G = xs.shape[0]
         fine0 = np.asarray(fine0)
         bounds_tok = patch_bounds(self.plan.patches)
         bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
         workers = [i for i in plan.active if self.plan.patches[i] > 0]
-        tshape = (G,) + (1,) * (xs.ndim - 1)
 
         pending, slabs = {}, {}
         for i in workers:
@@ -400,8 +392,7 @@ class PipefuseStepper(EmulatedStepper):
                     self.params, cfg, slabs[i], t_from, conds, ctx_k, ctx_v,
                     bounds_tok[i][0], self.bounds)
                 slabs[i] = sampler_lib.ddim_step(self.sched, slabs[i], eps,
-                                                 t_from.reshape(tshape),
-                                                 t_to.reshape(tshape))
+                                                 t_from, t_to)
                 if f == 0:
                     pending[i] = (k, v)
         for i in workers:

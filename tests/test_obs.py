@@ -73,6 +73,52 @@ def _parent(spans, child, names):
     return max(outer, key=lambda s: s[0]) if outer else None
 
 
+def _launches_in_sampler_spans(path):
+    """The launches made inside each ``exec.sampler`` span of the trace
+    under ``path``, counted as ``bench/program_trace.py`` counts them: a
+    ``PjitFunction(...)`` event on the span's thread, once where a second
+    one nests inside it."""
+    from jax.profiler import ProfileData
+    (xplane,) = path.glob("plugins/profile/*/*.xplane.pb")
+    counts = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            events = [(int(e.start_ns), int(e.end_ns), e.name)
+                      for e in line.events]
+            calls, end = [], None
+            for a, b, name in sorted(events):
+                if name.startswith("PjitFunction") and (end is None
+                                                        or a >= end):
+                    calls.append(a)
+                    end = b
+            counts += [sum(a <= t < b for t in calls)
+                       for a, b, name in events if name == "exec.sampler"]
+    return counts
+
+
+@pytest.mark.parametrize("path", ["generate", "engine"])
+def test_each_sampler_span_is_one_launch(pipe, tmp_path, path):
+    """Every DDIM update is one compiled program: in a traced ``generate``
+    and in traced engine rounds (two warm-up rounds and an adaptive one)
+    each ``exec.sampler`` span holds exactly one launch."""
+    xs, conds = _inputs(pipe.model_cfg, 2)
+
+    def fresh_run():
+        if path == "generate":
+            return lambda: pipe.generate(xs[0], conds[0]).image
+        engine = DiffusionServingEngine(pipe, slots=SLOTS)
+        for x, c in zip(xs, conds):
+            engine.submit(x, c)
+        return lambda: [engine.step() for _ in range(3)]
+
+    fresh_run()()                        # compile outside the trace
+    _traced(tmp_path, fresh_run())
+    counts = _launches_in_sampler_spans(tmp_path)
+    assert counts and counts == [1] * len(counts), counts
+
+
 def test_every_span_in_the_source_is_declared():
     used = set()
     for root, _, files in os.walk(SRC):

@@ -41,6 +41,57 @@ def test_ddpm_runs_finite():
     assert np.all(np.isfinite(np.asarray(out)))
 
 
+def _ddim_op_by_op(sched, x, eps, t_from, t_to):
+    """The DDIM update as separate eager operations, the timesteps laid
+    over x's leading axes."""
+    lanes = jnp.shape(t_from) + (1,) * (x.ndim - jnp.ndim(t_from))
+    t_from, t_to = jnp.reshape(t_from, lanes), jnp.reshape(t_to, lanes)
+    a_from, a_to = sched.alpha(t_from), sched.alpha(t_to)
+    s_from, s_to = sched.sigma(t_from), sched.sigma(t_to)
+    coef = a_to * s_from / a_from - s_to
+    out = (a_to / a_from) * x.astype(jnp.float32) \
+        - coef * eps.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+@pytest.mark.parametrize("case", ["scalar", "lanes", "endpoint"])
+def test_compiled_ddim_step_is_the_op_by_op_update(case):
+    """One compiled program per update, bitwise the eager formula: a scalar
+    step, per-lane [G] timesteps over a [G,1,H,W,C] lane stack, and the
+    t_to = 0 endpoint; a second call at the same shapes reuses the
+    program."""
+    sched = sl.linear_schedule(1000)
+    ts = sl.ddim_timesteps(sched.T, 20)
+    kx, ke = jax.random.split(jax.random.PRNGKey(3))
+    if case == "lanes":
+        shape, dtype = (5, 1, 6, 8, 4), jnp.bfloat16
+        t_from, t_to = ts[jnp.array([0, 3, 9, 15, 19])], \
+            ts[jnp.array([1, 5, 10, 17, 20])]
+    else:
+        shape, dtype = (1, 6, 8, 4), jnp.float32
+        m = 19 if case == "endpoint" else 4
+        t_from, t_to = ts[m], ts[m + 1]
+    assert (int(jnp.min(t_to)) == 0) == (case != "scalar")
+
+    def draw(i):
+        x = jax.random.normal(jax.random.fold_in(kx, i), shape)
+        eps = jax.random.normal(jax.random.fold_in(ke, i), shape)
+        return x.astype(dtype), eps.astype(dtype)
+
+    programs = sl._ddim_program._cache_size()
+    for i in range(2):
+        x, eps = draw(i)
+        got = sl.ddim_step(sched, x, eps, t_from, t_to)
+        if i == 0:
+            programs = sl._ddim_program._cache_size()
+        want = _ddim_op_by_op(sched, x, eps, t_from, t_to)
+        assert got.dtype == dtype and got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    assert sl._ddim_program._cache_size() == programs
+
+
 def test_theorem1_redundancy_order():
     """|x_{t_m} - x_{t_{m+1}}| max-step-difference scales ~ 1/M (Thm. 1)."""
     sched = sl.linear_schedule(1000)
