@@ -2,7 +2,7 @@
 
 Each assigned architecture (public-literature pool) has one module here with
 the exact assigned config; ``sdxl_dit`` / ``tiny_dit`` / ``tiny_unet`` are the
-paper's own diffusion models.
+paper's own diffusion models, ``sd3_medium`` the MMDiT denoiser of SD3.
 """
 from __future__ import annotations
 
@@ -25,7 +25,7 @@ ASSIGNED: List[str] = [
     "internvl2-76b",
 ]
 
-DIFFUSION: List[str] = ["sdxl-dit", "tiny-dit"]
+DIFFUSION: List[str] = ["sdxl-dit", "sd3-medium", "tiny-dit"]
 
 ALL_ARCHS: List[str] = ASSIGNED + DIFFUSION
 

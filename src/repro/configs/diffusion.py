@@ -29,6 +29,15 @@ class DiTConfig:
     # params are drawn and no new ops are traced.
     cond_seq_len: int = 0
     cross_attn: bool = False
+    # MMDiT (family "mmdit", DESIGN.md §18; SD3, arXiv:2403.03206): the
+    # prompt joins self-attention as a second token stream of cond_seq_len
+    # tokens of cond_dim channels, and a pooled prompt vector of pooled_dim
+    # channels joins the timestep in the adaLN conditioning. The position
+    # table is a pos_embed_max_size square cropped to its centre, and
+    # flow_shift is the rectified-flow sampler's timestep shift.
+    pooled_dim: int = 0
+    pos_embed_max_size: int = 0
+    flow_shift: float = 1.0
     # numerics
     param_dtype: str = "float32"
     dtype: str = "float32"

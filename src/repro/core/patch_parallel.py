@@ -160,6 +160,10 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                  seq=None) -> RunResult:
     """Execute Algorithm 1 by interpreting the schedule IR event stream.
 
+    sched: a :class:`~repro.core.sampler.NoiseSchedule` (DDIM updates of
+    eps) or a :class:`~repro.core.sampler.FlowSchedule` (Euler updates of
+    the velocity an MMDiT predicts; cond is then its ``TextCond``).
+
     patches: token-rows per worker (sum == cfg.tokens_per_side; 0 = excluded).
     Uniform plan (all ratios 1, equal patches) == DistriFusion patch
     parallelism; plan from Eq. 4/5 == STADI.
@@ -196,7 +200,7 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
     M_base = plan.m_base
     plan0, patches0 = plan, list(patches)  # trace provenance: the initial
     # allocation; per-interval events record what actually executed
-    ts = sampler_lib.ddim_timesteps(sched.T, M_base)   # fine grid, len M_base+1
+    ts = sampler_lib.timesteps(sched, M_base)   # fine grid, len M_base+1
     policy = comm_lib.get_exchange(exchange, exchange_refresh)
     guided = guidance is not None
     if guided:
@@ -206,6 +210,9 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
             raise ValueError("online rebalancing is not supported with "
                              "guidance (the branch pairing is static)")
     tok_axis = 3 if guided else 2        # buffers gain a leading branch axis
+    # an MMDiT dispatch carries the prompt's context tokens besides its rows
+    ctx = ({"ctx": int(cond.context.shape[-2])} if cfg.family == "mmdit"
+           else {})
 
     x = x_T
     B = x.shape[0]
@@ -242,12 +249,12 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
             with obs.span("exec.warmup"):
                 with obs.span("exec.buffers"):
                     t = ts[ev.fine_step]
-                with obs.span("exec.model", rows=P):
+                with obs.span("exec.model", rows=P, **ctx):
                     eps, kvs = _full_step(t)
                 with obs.span("exec.buffers"):
                     t_from, t_to = ts[ev.fine_step], ts[ev.fine_step + 1]
                 with obs.span("exec.sampler"):
-                    x = sampler_lib.ddim_step(sched, x, eps, t_from, t_to)
+                    x = sampler_lib.step(sched, x, eps, t_from, t_to)
                 published = buf_lib.Published(kvs[0], kvs[1], ev.fine_step)
                 read_pub = published
                 records.append(ir.warmup_record(ev))
@@ -265,7 +272,7 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                 if published is None:    # M_w == 0: bootstrap buffers once
                     with obs.span("exec.buffers"):
                         t = ts[0]
-                    with obs.span("exec.model", rows=P):
+                    with obs.span("exec.model", rows=P, **ctx):
                         _, kvs = _full_step(t)
                     published = buf_lib.Published(kvs[0], kvs[1], -1)
                     read_pub = published
@@ -283,7 +290,8 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                         with obs.span("exec.buffers"):
                             t_from = ts[ev.fine_step + s * r]
                             t_to = ts[ev.fine_step + (s + 1) * r]
-                        with obs.span("exec.model", rows=ev.patches[i]):
+                        with obs.span("exec.model", rows=ev.patches[i],
+                                      **ctx):
                             if not guided:
                                 eps, kvs = _jit_patch_step(
                                     params, cfg, x_loc, t_from, cond,
@@ -295,8 +303,8 @@ def run_schedule(params, cfg: DiTConfig, sched: NoiseSchedule, x_T, cond,
                                     guidance, fresh, ucache, i,
                                     first=(s == 0))
                         with obs.span("exec.sampler"):
-                            x_loc = sampler_lib.ddim_step(sched, x_loc, eps,
-                                                          t_from, t_to)
+                            x_loc = sampler_lib.step(sched, x_loc, eps,
+                                                     t_from, t_to)
                         # Alg.1 l.16-17 / l.23: publish at interval start
                         if s == 0:
                             with obs.span("exec.buffers"):

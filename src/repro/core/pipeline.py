@@ -57,7 +57,7 @@ from repro.core import simulate as sim
 from repro.core.hetero import DeviceProfile
 from repro.core.patch_parallel import ExecutionTrace
 from repro.core.planners import ExecutionPlan, get_planner
-from repro.core.sampler import NoiseSchedule
+from repro.core.sampler import FlowSchedule, NoiseSchedule
 from repro.core.simulate import CostModel
 
 
@@ -883,6 +883,24 @@ class StadiPipeline:
                 f"cond_bucket={config.cond_bucket} exceeds the model's "
                 f"cond_seq_len={model_cfg.cond_seq_len} (the encoder "
                 "never emits a longer prompt bucket)")
+        # MMDiT and the flow sampler (DESIGN.md §18) run on the emulated
+        # executor (and the trace-only simulator) alone
+        if model_cfg.family == "mmdit" or isinstance(sched, FlowSchedule):
+            what = (f"the {model_cfg.family!r} family"
+                    if model_cfg.family == "mmdit"
+                    else "the flow-matching sampler")
+            for bad, name in (
+                    (config.backend not in ("emulated", "simulate"),
+                     f"backend {config.backend!r}"),
+                    (guided, "classifier-free guidance"),
+                    (config.seq_shards != 1, "sequence sharding"),
+                    (config.num_frames != 1, "the frame axis"),
+                    (config.num_stages != 1, "the displaced patch pipeline")):
+                if bad:
+                    raise ValueError(
+                        f"{what} runs on the 'emulated' backend without "
+                        f"guidance, sequence sharding, frames or stages; "
+                        f"not with {name}")
         # persistent plan cache (DESIGN.md §14)
         self.plan_cache = None
         self.last_plan_key: Optional[str] = None

@@ -1,7 +1,9 @@
 """Diffusion samplers: DDPM ancestral, DDIM / DPM-Solver-1 (paper Lemma 1),
 and the noise schedules they share. All in VP (variance-preserving)
 parameterization: alpha_t = sqrt(alpha_bar_t), sigma_t = sqrt(1 - alpha_bar_t),
-lambda_t = log(alpha_t / sigma_t)  (log-SNR/2).
+lambda_t = log(alpha_t / sigma_t)  (log-SNR/2). Beside them the rectified-
+flow Euler sampler of SD3 (:class:`FlowSchedule`); :func:`timesteps` and
+:func:`step` pick the update from the schedule's type.
 
 The paper's Lemma 1 (DPM-Solver-1 == DDIM):
     x_{t_m} = (alpha_{t_m}/alpha_{t_{m-1}}) x_{t_{m-1}}
@@ -15,6 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +70,39 @@ def cosine_schedule(T: int = 1000, s: float = 8e-3) -> NoiseSchedule:
 def ddim_timesteps(T: int, M: int, warmup_offset: int = 0) -> jnp.ndarray:
     """M+1 decreasing timesteps t_0=T .. t_M=0 (paper Lemma 1 grid)."""
     return jnp.round(jnp.linspace(T, 0, M + 1)).astype(jnp.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class FlowSchedule:
+    """Rectified flow (arXiv:2403.03206): x_sigma = (1 - sigma) x_0 +
+    sigma * noise, and the model predicts the velocity noise - x_0 at the
+    timestep T * sigma. The sigma grid is that of diffusers'
+    ``FlowMatchEulerDiscreteScheduler`` with a static ``shift``."""
+    T: int = 1000
+    shift: float = 1.0
+
+    def shifted(self, sigma):
+        return self.shift * sigma / (1 + (self.shift - 1) * sigma)
+
+    def sigmas(self, M: int) -> np.ndarray:
+        """M + 1 decreasing sigmas, the last 0: M points evenly spaced from
+        1 to the training grid's smallest (shifted) sigma, shifted again,
+        as ``set_timesteps`` spaces them. float64 arithmetic, float32 out."""
+        sigma_min = self.shifted(1.0 / self.T)
+        s = self.shifted(np.linspace(1.0, sigma_min, M))
+        return np.append(s, 0.0).astype(np.float32)
+
+
+jax.tree_util.register_dataclass(FlowSchedule, data_fields=[],
+                                 meta_fields=["T", "shift"])
+
+
+def timesteps(sched, M: int) -> jnp.ndarray:
+    """The fine grid of M steps: M + 1 decreasing model timesteps, the last
+    0 (DDIM's integer grid, or T * sigma of a flow schedule, float32)."""
+    if isinstance(sched, FlowSchedule):
+        return jnp.asarray(sched.T * sched.sigmas(M))
+    return ddim_timesteps(sched.T, M)
 
 
 # ----------------------------------------------------------------------
@@ -138,6 +174,36 @@ def _ddim_update(sched, x, eps, t_from, t_to):
 # passes otherwise and fuses as before.)
 _ddim_program = jax.jit(_ddim_update,
                         compiler_options={"xla_disable_hlo_passes": "fusion"})
+
+
+def step(sched, x, out, t_from, t_to):
+    """One update of the fine grid from t_from to t_to with the model's
+    output: DDIM (out = eps) on a :class:`NoiseSchedule`, Euler (out =
+    velocity) on a :class:`FlowSchedule`."""
+    if isinstance(sched, FlowSchedule):
+        return flow_step(sched, x, out, t_from, t_to)
+    return ddim_step(sched, x, out, t_from, t_to)
+
+
+def flow_step(sched: FlowSchedule, x, v, t_from, t_to):
+    """The Euler update of rectified flow, x + (sigma_to - sigma_from) * v.
+    One compiled program called eagerly, traced inline inside traced code,
+    and broadcast over lanes, as :func:`ddim_step`."""
+    args = (sched, x, v, t_from, t_to)
+    if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
+        return _flow_update(*args)
+    return _flow_program(*args)
+
+
+def _flow_update(sched, x, v, t_from, t_to):
+    dt = (jnp.asarray(t_to, jnp.float32)
+          - jnp.asarray(t_from, jnp.float32)) / sched.T
+    lanes = dt.shape + (1,) * (x.ndim - dt.ndim)
+    out = x.astype(jnp.float32) + dt.reshape(lanes) * v.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+_flow_program = jax.jit(_flow_update)
 
 
 def ddpm_step(sched: NoiseSchedule, x, eps, t, noise):

@@ -62,6 +62,9 @@ def pos_embed_2d(hp: int, wp: int, dim: int):
 # ----------------------------------------------------------------------
 
 def init_params(key, cfg: DiTConfig):
+    if cfg.family == "mmdit":
+        from repro.models.diffusion import mmdit
+        return mmdit.init_params(key, cfg)
     dt = jnp.dtype(cfg.param_dtype)
     D, L = cfg.d_model, cfg.n_layers
     F = int(cfg.mlp_ratio * D)
@@ -451,7 +454,19 @@ def forward_patch(params, cfg: DiTConfig, x_rows, t, cond,
                may be traced — summed into the conditioning vector.
 
     Returns (eps_rows [B, rows_local, W, C], (fresh_k, fresh_v) [L,B,Nl,H,hd]).
+
+    An MMDiT config (``cfg.family == "mmdit"``) runs
+    :func:`repro.models.diffusion.mmdit.forward_patch` instead, on the
+    image-only buffers: it has no padded, sequence-sharded or frame form.
     """
+    if cfg.family == "mmdit":
+        if any(a is not None
+               for a in (valid_tokens, attend_fn, frame, ctx_tokens)):
+            raise ValueError("the mmdit family runs unpadded, unsharded "
+                             "single-frame patches only")
+        from repro.models.diffusion import mmdit
+        return mmdit.forward_patch(params, cfg, x_rows, t, cond, row_start,
+                                   buffers=buffers, return_kv=return_kv)
     rows_tok = x_rows.shape[1] // cfg.patch_size         # token rows in patch
     h, c = embed_patch(params, cfg, x_rows, t, cond, row_start, frame=frame)
     tok_start = row_start * cfg.tokens_per_side

@@ -12,9 +12,10 @@ Call sites follow three rules: spans open on the host, never inside a
 jitted function; their arguments are values the host already holds (never
 a device value, which would wait for the device); and a span never moves a
 dispatch, so results are bitwise the same with the profiler on or off.
-The only arguments are those ``bench/program_trace.py`` reads: ``rows`` on
-``exec.model``, and ``lanes`` and ``padded`` on the engine's
-``exec.warmup`` and ``exec.interval``.
+The only arguments are those the benchmark reads: ``rows`` on
+``exec.model`` (and ``ctx``, the context tokens of an MMDiT dispatch), and
+``lanes`` and ``padded`` on the engine's ``exec.warmup`` and
+``exec.interval``.
 """
 from __future__ import annotations
 
@@ -27,8 +28,9 @@ SPANS = (
                          # of a lane group: lanes (real), padded (pad lanes)
     "exec.interval",     # one adaptive interval over all workers; in the
                          # engine of a lane group: lanes, padded
-    "exec.model",        # one jitted denoiser dispatch; rows (token rows)
-    "exec.sampler",      # one DDIM update (one compiled program)
+    "exec.model",        # one jitted denoiser dispatch; rows (token rows),
+                         # MMDiT: ctx (context tokens)
+    "exec.sampler",      # one DDIM or flow update (one compiled program)
     "exec.buffers",      # slab slices and write-backs, timestep reads, K/V
     "exec.exchange",     # an interval boundary: slab write-back, K/V merge
     "exec.record",       # the execution trace built at the end of a run

@@ -486,6 +486,14 @@ class DiffusionServingEngine:
                  rebalance_threshold: float = 0.2,
                  measured_speeds: Optional[Sequence[float]] = None):
         config = pipeline.config
+        if pipeline.model_cfg.family == "mmdit":
+            raise ValueError("the serving engine runs DiT lanes; the "
+                             "'mmdit' family runs through "
+                             "StadiPipeline.generate")
+        if isinstance(pipeline.sched, sampler_lib.FlowSchedule):
+            raise ValueError("the serving engine runs DDIM lanes; the "
+                             "flow-matching sampler runs through "
+                             "StadiPipeline.generate")
         if config.rebalance_every:
             raise ValueError("serving drives placement per round; disable "
                              "rebalance_every on the pipeline config (the "
