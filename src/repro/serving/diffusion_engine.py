@@ -16,7 +16,8 @@ step for warmup-phase lanes -> one adaptive interval (``plan.lcm`` fine
 steps) for adaptive-phase lanes -> retire finished lanes. All per-lane state
 (latent, stale-KV ``Published`` buffers, class condition) lives in
 slot-major stacked arrays, so a batched step is a gather / one vmapped
-denoiser dispatch / scatter.
+denoiser dispatch / scatter over a lane group: a lone lane runs alone,
+a larger group is padded to ``slots``.
 
 Numerics: the "emulated" stepper mirrors ``patch_parallel.run_schedule``
 call-for-call — same jit boundaries, the same compiled DDIM update,
@@ -34,9 +35,11 @@ service latency and SLO accounting that tests can assert exactly.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 import time
+from collections import Counter
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -195,6 +198,37 @@ def _vmap_guided_patch_step(params, cfg, xs_loc, ts, conds, bk2s, bv2s,
     return jax.vmap(one)(xs_loc, ts, conds, bk2s, bv2s, scales)
 
 
+# Slab and K/V buffer work around the lane steps, one program each (the
+# shapes, bounds and axes they are compiled for are few: one set a lane
+# width).
+
+@functools.partial(jax.jit, static_argnames=("bounds",))
+def _slice_rows(xs, bounds):
+    """The row slabs ``xs[:, :, lo:hi]`` for each (lo, hi) of ``bounds``."""
+    return tuple(xs[:, :, lo:hi] for lo, hi in bounds)
+
+
+@functools.partial(jax.jit, static_argnames=("starts",))
+def _write_rows(xs, slabs, starts):
+    """``xs`` with each slab written back at its first row, in order."""
+    for slab, lo in zip(slabs, starts):
+        xs = xs.at[:, :, lo:lo + slab.shape[2]].set(slab)
+    return xs
+
+
+@functools.partial(jax.jit, static_argnames=("starts", "axis"))
+def _merge_tokens(pub_k, pub_v, kvs, starts, axis):
+    """The published K/V with each worker's fresh (k, v) written at its
+    first token along ``axis``, in the order given (``buffers.merge``'s
+    ascending worker order), cast to the buffers' dtype."""
+    for (k, v), start in zip(kvs, starts):
+        pub_k = jax.lax.dynamic_update_slice_in_dim(
+            pub_k, k.astype(pub_k.dtype), start, axis=axis)
+        pub_v = jax.lax.dynamic_update_slice_in_dim(
+            pub_v, v.astype(pub_v.dtype), start, axis=axis)
+    return pub_k, pub_v
+
+
 class _VmapWarmupMixin:
     """Warmup / bootstrap steps shared by both steppers: synchronous
     full-image forwards, vmapped over lanes (per-lane timestep)."""
@@ -247,8 +281,9 @@ class EmulatedStepper(_VmapWarmupMixin):
                  slots: int):
         self._init_warmup(pipeline.params, pipeline.model_cfg, pipeline.sched)
         self.plan = plan
-        self._ts = sampler_lib.ddim_timesteps(pipeline.sched.T,
-                                              plan.temporal.m_base)
+        # on the host: a lane's timesteps are read without a launch
+        self._ts = np.asarray(sampler_lib.ddim_timesteps(
+            pipeline.sched.T, plan.temporal.m_base))
 
     def _interval_impl(self, xs, fine0, conds, pub_k, pub_v, merge,
                        step_fn, tok_axis):
@@ -264,12 +299,13 @@ class EmulatedStepper(_VmapWarmupMixin):
         bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
         workers = [i for i in plan.active if self.plan.patches[i] > 0]
 
-        pending, new_slabs = {}, {}
+        with obs.span("exec.buffers"):
+            slabs = dict(zip(workers, _slice_rows(
+                xs, tuple(bounds_lat[i] for i in workers))))
+        pending = {}
         for i in workers:
             r = plan.ratios[i]
-            lo, hi = bounds_lat[i]
-            with obs.span("exec.buffers"):
-                x_loc = xs[:, :, lo:hi]
+            x_loc = slabs[i]
             for s in range(R // r):
                 with obs.span("exec.buffers"):
                     t_from = self._ts[fine0 + s * r]
@@ -281,21 +317,18 @@ class EmulatedStepper(_VmapWarmupMixin):
                                                   t_from, t_to)
                 if s == 0:           # Alg.1: publish the first substep's KV
                     pending[i] = (k, v)
-            new_slabs[i] = x_loc
+            slabs[i] = x_loc
         # interval boundary: all-gather of x + buffer merge (same order as
         # buffers.merge: ascending worker id)
         with obs.span("exec.buffers"):
-            for i in workers:
-                lo, hi = bounds_lat[i]
-                xs = xs.at[:, :, lo:hi].set(new_slabs[i])
+            xs = _write_rows(xs, tuple(slabs[i] for i in workers),
+                             tuple(bounds_lat[i][0] for i in workers))
             if merge:
-                for i in sorted(pending):
-                    k, v = pending[i]
-                    start = bounds_tok[i][0] * cfg.tokens_per_side
-                    pub_k = jax.lax.dynamic_update_slice_in_dim(
-                        pub_k, k.astype(pub_k.dtype), start, axis=tok_axis)
-                    pub_v = jax.lax.dynamic_update_slice_in_dim(
-                        pub_v, v.astype(pub_v.dtype), start, axis=tok_axis)
+                order = sorted(pending)
+                pub_k, pub_v = _merge_tokens(
+                    pub_k, pub_v, tuple(pending[i] for i in order),
+                    tuple(bounds_tok[i][0] * cfg.tokens_per_side
+                          for i in order), tok_axis)
         return xs, pub_k, pub_v
 
     def interval(self, xs, fine0, conds, pub_k, pub_v, merge: bool = True):
@@ -377,10 +410,9 @@ class PipefuseStepper(EmulatedStepper):
         bounds_lat = [(a * p, b * p) for a, b in bounds_tok]
         workers = [i for i in plan.active if self.plan.patches[i] > 0]
 
-        pending, slabs = {}, {}
-        for i in workers:
-            lo, hi = bounds_lat[i]
-            slabs[i] = xs[:, :, lo:hi]
+        slabs = dict(zip(workers, _slice_rows(
+            xs, tuple(bounds_lat[i] for i in workers))))
+        pending = {}
         for f in range(R):                   # substep-major micro order
             for i in workers:
                 r = plan.ratios[i]
@@ -395,17 +427,14 @@ class PipefuseStepper(EmulatedStepper):
                                                  t_from, t_to)
                 if f == 0:
                     pending[i] = (k, v)
-        for i in workers:
-            lo, hi = bounds_lat[i]
-            xs = xs.at[:, :, lo:hi].set(slabs[i])
+        xs = _write_rows(xs, tuple(slabs[i] for i in workers),
+                         tuple(bounds_lat[i][0] for i in workers))
         if merge:
-            for i in sorted(pending):
-                k, v = pending[i]
-                start = bounds_tok[i][0] * cfg.tokens_per_side
-                pub_k = jax.lax.dynamic_update_slice_in_dim(
-                    pub_k, k.astype(pub_k.dtype), start, axis=3)
-                pub_v = jax.lax.dynamic_update_slice_in_dim(
-                    pub_v, v.astype(pub_v.dtype), start, axis=3)
+            order = sorted(pending)
+            pub_k, pub_v = _merge_tokens(
+                pub_k, pub_v, tuple(pending[i] for i in order),
+                tuple(bounds_tok[i][0] * cfg.tokens_per_side for i in order),
+                axis=3)
         return xs, pub_k, pub_v, ctx_k, ctx_v
 
 
@@ -469,6 +498,32 @@ class SpmdStepper(_VmapWarmupMixin):
 # the engine
 # ----------------------------------------------------------------------
 
+@jax.jit
+def _take_lanes(arrays, idx):
+    """The lanes ``idx`` of each slot-major array, in one program."""
+    return tuple(a[idx] for a in arrays)
+
+
+@jax.jit
+def _put_lanes(arrays, idx, values):
+    """Each slot-major array with ``values`` written into its lanes
+    ``idx``, in one program. The lanes' K/V comes out in the activation
+    dtype (f32 latents promote a bf16 model's activations) and is cast to
+    the buffer's; a repeated lane carries equal values, so the write order
+    does not matter."""
+    return tuple(a.at[idx].set(v.astype(a.dtype))
+                 for a, v in zip(arrays, values))
+
+
+def lane_ladder(slots: int) -> List[int]:
+    """The widths a lane group is dispatched at: 1 for a lone lane, else
+    ``slots``. Each width costs set-up, since construction loads (or
+    compiles) its programs, among them a denoiser step for each of the
+    plan's step shapes; a lone lane is the common group below capacity,
+    so no width between the two is readied."""
+    return sorted({1, slots})
+
+
 class DiffusionServingEngine:
     """Continuous batching of diffusion requests over one StadiPipeline.
 
@@ -478,6 +533,15 @@ class DiffusionServingEngine:
     cost model (heaviest load -> fastest device, deterministic ties), and the
     modeled round time — batched compute, boundary all-gather, masked async
     KV — is accrued to every in-flight request.
+
+    Lane widths: a lone plain lane is dispatched alone and any larger
+    group at ``slots`` (:func:`lane_ladder`, :meth:`_pad`), so the denoiser
+    computes no padding when one request is in flight; guided groups keep
+    the full ``slots`` width. The lane buffer work around each step is one
+    program. Construction runs both widths once on scratch lanes
+    (:meth:`_ready_rungs`), so no width of plain class-conditional lanes
+    compiles while serving. Each round ends by waiting for the one before
+    it, so the host runs at most one round ahead of the device.
     """
 
     def __init__(self, pipeline: StadiPipeline, *, slots: int = 4,
@@ -525,8 +589,8 @@ class DiffusionServingEngine:
         if self.cm is None:
             self.cm = CostModel(t_fixed=1e-3, t_row=1e-3)
         cfg = pipeline.model_cfg
-        self._ts = sampler_lib.ddim_timesteps(pipeline.sched.T,
-                                              self.plan.temporal.m_base)
+        self._ts = np.asarray(sampler_lib.ddim_timesteps(
+            pipeline.sched.T, self.plan.temporal.m_base))
         H, C = cfg.latent_size, cfg.channels
         self._x = jnp.zeros((slots, 1, H, H, C), jnp.float32)
         kshape = (slots,) + dit.buffer_shape(cfg, 1)
@@ -635,12 +699,15 @@ class DiffusionServingEngine:
         self.rounds: List[RoundReport] = []
         self.modeled_clock_s = 0.0
         self._next_uid = 0
+        self.lane_widths: Counter = Counter()   # dispatch width -> groups
+        self._last_x = None          # the lane latents the last round left
         self._install_plan(self.plan)
         if self.rebalance_every and self.stepper.cohort_only:
             raise ValueError("engine replanning rebuilds the lane stepper "
                              "per plan; the cohort-only (spmd) stepper "
                              "compiles one static program — serve it with "
                              "rebalance_every=0")
+        self._ready_rungs()
 
     def _install_plan(self, plan: ExecutionPlan) -> None:
         """(Re)build every plan-derived piece of engine state: the lane
@@ -981,27 +1048,14 @@ class DiffusionServingEngine:
 
     def _warmup_phase(self, report: RoundReport, warm: List[int]) -> None:
         """One synchronous fine step for every warm-up lane, one batched
-        dispatch per (guided, bucket) batch."""
+        dispatch per (guided, bucket) batch, each at its rung of the lane
+        ladder (:meth:`_pad`)."""
         for guided, bucket, lanes in self._by_guided(warm):
             with obs.span("engine.lanes"):
-                idx = self._pad(lanes)
+                idx = self._pad(lanes, guided)
                 fine = np.asarray([self.active[s].fine_step for s in idx])
-                args = (self._x[idx], self._ts[fine], self._ts[fine + 1],
-                        self._conds(idx))
-                if guided:
-                    args += (jnp.asarray(self._scales[idx]),)
-            with self._group_span("exec.warmup", lanes, idx):
-                out = (self.stepper.warmup_step_guided(*args) if guided
-                       else self.stepper.warmup_step(*args))
-            if guided:
-                xs, k2s, v2s = out
-                with obs.span("engine.lanes"):
-                    self._x = self._x.at[idx].set(xs)
-                    self._gk = self._gk.at[idx].set(k2s)
-                    self._gv = self._gv.at[idx].set(v2s)
-            else:
-                with obs.span("engine.lanes"):
-                    self._scatter(idx, *out)
+            self._warmup_group(lanes, idx, fine, guided)
+            self.lane_widths[len(idx)] += 1
             for s in lanes:
                 self.active[s].fine_step += 1
             with obs.span("engine.cost_model"):
@@ -1010,18 +1064,39 @@ class DiffusionServingEngine:
                                            cond_tokens=bucket)
             report.modeled_s += cost
 
+    def _warmup_group(self, lanes: Sequence[int], idx: np.ndarray,
+                      fine: np.ndarray, guided: bool) -> None:
+        """Gather, step and scatter back one padded warm-up lane group:
+        ``lanes`` real, ``idx`` the dispatched slots, ``fine`` their fine
+        steps."""
+        with obs.span("engine.lanes"):
+            (x,) = _take_lanes((self._x,), idx)
+            args = (x, self._ts[fine], self._ts[fine + 1], self._conds(idx))
+            if guided:
+                args += (jnp.asarray(self._scales[idx]),)
+        with self._group_span("exec.warmup", lanes, idx):
+            out = (self.stepper.warmup_step_guided(*args) if guided
+                   else self.stepper.warmup_step(*args))
+        with obs.span("engine.lanes"):
+            if guided:
+                self._x, self._gk, self._gv = _put_lanes(
+                    (self._x, self._gk, self._gv), idx, out)
+            else:
+                self._x, self._pub_k, self._pub_v = _put_lanes(
+                    (self._x, self._pub_k, self._pub_v), idx, out)
+
     def _adaptive_phase(self, report: RoundReport, adapt: List[int]) -> None:
         """One adaptive interval (plan.lcm fine steps) for every adaptive
-        lane, one batched interval per lane group."""
+        lane, one batched interval per lane group, each at its rung of the
+        lane ladder (:meth:`_pad`)."""
         R = self.plan.temporal.lcm
         placement = None
-        wants_ctx = getattr(self.stepper, "wants_ctx", False)
         for group, (read_factor, trail_kind, fill, seq_hops,
                     guided, bucket) in self._groups(adapt):
             merge = trail_kind == "full"
             if guided:               # branch-stacked per-lane CFG state
                 with obs.span("engine.lanes"):
-                    idx = self._pad(group)
+                    idx = self._pad(group, guided=True)
                     fine = np.asarray([self.active[s].fine_step
                                        for s in idx])
                     bk, bv = self._gk[idx], self._gv[idx]
@@ -1045,6 +1120,7 @@ class DiffusionServingEngine:
                                 self._gv[idx])
                         self._gk = self._gk.at[idx].set(ks)
                         self._gv = self._gv.at[idx].set(vs)
+                self.lane_widths[len(idx)] += 1
                 for s in group:
                     self.active[s].fine_step += R
                 with obs.span("engine.cost_model"):
@@ -1057,40 +1133,8 @@ class DiffusionServingEngine:
             with obs.span("engine.lanes"):
                 idx = self._pad(group)
                 fine = np.asarray([self.active[s].fine_step for s in idx])
-                bk, bv = self._pub_k[idx], self._pub_v[idx]
-                # predictive boundary before this group — staged steppers
-                # never read the extrapolation (ctx subsumes it), so skip
-                if read_factor and not wants_ctx:
-                    bk = buf_lib.extrapolate_arrays(bk, self._prev_k[idx],
-                                                    read_factor)
-                    bv = buf_lib.extrapolate_arrays(bv, self._prev_v[idx],
-                                                    read_factor)
-                if wants_ctx and fill:   # pipe refill: contexts <- published
-                    self._ctx_k = self._ctx_k.at[idx].set(self._pub_k[idx])
-                    self._ctx_v = self._ctx_v.at[idx].set(self._pub_v[idx])
-                args = (self._x[idx], fine, self._conds(idx), bk, bv)
-                if wants_ctx:
-                    args += (self._ctx_k[idx], self._ctx_v[idx])
-            with self._group_span("exec.interval", group, idx):
-                if wants_ctx:
-                    xs, ks, vs, ck, cv = self.stepper.interval_ctx(
-                        *args, merge=merge)
-                else:
-                    xs, ks, vs = self.stepper.interval(*args, merge=merge)
-            with obs.span("engine.lanes"):
-                if wants_ctx:
-                    self._ctx_k = self._ctx_k.at[idx].set(ck)
-                    self._ctx_v = self._ctx_v.at[idx].set(cv)
-                self._x = self._x.at[idx].set(xs)
-                if merge:
-                    if self._track_prev:
-                        # pre-merge buffers become the extrapolation base
-                        self._prev_k = self._prev_k.at[idx].set(
-                            self._pub_k[idx])
-                        self._prev_v = self._prev_v.at[idx].set(
-                            self._pub_v[idx])
-                    self._pub_k = self._pub_k.at[idx].set(ks)
-                    self._pub_v = self._pub_v.at[idx].set(vs)
+            self._interval_group(group, idx, fine, read_factor, merge, fill)
+            self.lane_widths[len(idx)] += 1
             for s in group:
                 self.active[s].fine_step += R
             with obs.span("engine.cost_model"):
@@ -1111,6 +1155,87 @@ class DiffusionServingEngine:
                     self._rounds_since_check = 0
                     self._maybe_replan()
 
+    def _interval_group(self, lanes: Sequence[int], idx: np.ndarray,
+                        fine: np.ndarray, read_factor: float, merge: bool,
+                        fill: bool) -> None:
+        """Gather, run one adaptive interval for, and scatter back one
+        padded plain lane group (``lanes`` real, ``idx`` the dispatched
+        slots, ``fine`` their fine steps)."""
+        wants_ctx = getattr(self.stepper, "wants_ctx", False)
+        with obs.span("engine.lanes"):
+            x, bk, bv = _take_lanes((self._x, self._pub_k, self._pub_v), idx)
+            # predictive boundary before this group — staged steppers
+            # never read the extrapolation (ctx subsumes it), so skip
+            if read_factor and not wants_ctx:
+                bk = buf_lib.extrapolate_arrays(bk, self._prev_k[idx],
+                                                read_factor)
+                bv = buf_lib.extrapolate_arrays(bv, self._prev_v[idx],
+                                                read_factor)
+            if wants_ctx and fill:   # pipe refill: contexts <- published
+                self._ctx_k = self._ctx_k.at[idx].set(self._pub_k[idx])
+                self._ctx_v = self._ctx_v.at[idx].set(self._pub_v[idx])
+            args = (x, fine, self._conds(idx), bk, bv)
+            if wants_ctx:
+                args += (self._ctx_k[idx], self._ctx_v[idx])
+        with self._group_span("exec.interval", lanes, idx):
+            if wants_ctx:
+                xs, ks, vs, ck, cv = self.stepper.interval_ctx(
+                    *args, merge=merge)
+            else:
+                xs, ks, vs = self.stepper.interval(*args, merge=merge)
+        with obs.span("engine.lanes"):
+            if wants_ctx:
+                self._ctx_k = self._ctx_k.at[idx].set(ck)
+                self._ctx_v = self._ctx_v.at[idx].set(cv)
+            if merge:
+                if self._track_prev:
+                    # pre-merge buffers become the extrapolation base
+                    self._prev_k = self._prev_k.at[idx].set(
+                        self._pub_k[idx])
+                    self._prev_v = self._prev_v.at[idx].set(
+                        self._pub_v[idx])
+                self._x, self._pub_k, self._pub_v = _put_lanes(
+                    (self._x, self._pub_k, self._pub_v), idx, (xs, ks, vs))
+            else:
+                (self._x,) = _put_lanes((self._x,), idx, (xs,))
+
+    def _ready_rungs(self) -> None:
+        """Run every rung of the lane ladder once on scratch lanes: one
+        warm-up step and one adaptive interval of each boundary behaviour
+        the plan has, through the same gathers, steps and scatters as a
+        round, so each rung's programs are compiled (or loaded) here and
+        not inside serving. Each rung runs on a shallow copy of the
+        engine whose lane state it alone rebinds, so the engine's own
+        lanes stay untouched. An engine built after another of the same
+        deployment finds every program in the jit caches, so its rehearsal
+        is a few dispatches a rung.
+
+        Guided lanes (always at ``slots``, :meth:`_pad`) compile at their
+        first dispatch, prompt-conditioned lanes at each length bucket's
+        first dispatch (the buckets requests will bring are unknown here),
+        and a replanned engine as its rounds first use the new plan's
+        programs."""
+        if self.stepper is None or self._prompt_mode:
+            return
+        kinds: Dict[Tuple[bool, bool, bool], Tuple[int, float, bool, bool]] \
+            = {}
+        for fine0, (read_factor, kind, fill, _) in sorted(
+                self._interval_info.items()):
+            merge = kind == "full"
+            kinds.setdefault((bool(read_factor), merge, fill),
+                             (fine0, read_factor, merge, fill))
+        for width in lane_ladder(self.slots):
+            scratch = copy.copy(self)
+            idx = scratch._pad([0] * width)
+            if self.plan.temporal.m_warmup:
+                scratch._warmup_group(idx[:1], idx, np.zeros(width, int),
+                                      guided=False)
+            for fine0, read_factor, merge, fill in kinds.values():
+                scratch._interval_group(idx[:1], idx,
+                                        np.full(width, fine0),
+                                        read_factor, merge, fill)
+            jax.block_until_ready(scratch._x)
+
     @staticmethod
     def _group_span(name: str, lanes: Sequence[int], idx: np.ndarray):
         """The span of one stepper call over a lane group: ``lanes`` real
@@ -1127,6 +1252,11 @@ class DiffusionServingEngine:
                           if r.fine_step >= M_base]
             if done_slots:       # flush async dispatch BEFORE stamping wall
                 jax.block_until_ready(self._x)
+            elif self._last_x is not None:
+                # at most one round in flight: each queued round would hold
+                # a new copy of the lane state and its steps' K/V
+                jax.block_until_ready(self._last_x)
+            self._last_x = self._x
             finished = []
             for slot in done_slots:
                 req = self.active.pop(slot)
@@ -1198,12 +1328,23 @@ class DiffusionServingEngine:
 
     # ---------------- lane plumbing ----------------
 
-    def _pad(self, lanes: Sequence[int]) -> np.ndarray:
-        """Pad a lane group to the full slot count (stable jit shapes) by
-        repeating the first lane; duplicate lanes compute duplicate values,
-        so the scatter-back is value-identical regardless of write order."""
-        return np.asarray(list(lanes)
-                          + [lanes[0]] * (self.slots - len(lanes)))
+    def _pad(self, lanes: Sequence[int], guided: bool = False) -> np.ndarray:
+        """Pad a lane group to its dispatch width by repeating the first
+        lane. A plain group runs at the narrowest rung of
+        :func:`lane_ladder` that holds its lanes, so a few programs of fixed
+        shape serve every load and few padded rows are computed at light
+        load. Lanes are computed independently inside the vmapped steps,
+        so a duplicate lane computes exactly its original's values and the
+        scatter-back is value-identical whatever the write order.
+
+        A guided group always runs at ``slots``: XLA's CPU backend rounds
+        the two-level (lane, branch) vmapped step differently at other
+        widths, which would break the guided lanes' bitwise match with
+        ``generate`` there."""
+        n = len(lanes)
+        width = self.slots if guided else next(
+            w for w in lane_ladder(self.slots) if w >= n)
+        return np.asarray(list(lanes) + [lanes[0]] * (width - n))
 
     def _conds(self, idx: np.ndarray) -> jnp.ndarray:
         """Lane-stacked conditioning for a padded lane group: the
@@ -1212,15 +1353,8 @@ class DiffusionServingEngine:
         lane-group key pins one length bucket L per dispatch, so the
         stack is rectangular by construction."""
         if not self._prompt_mode:
-            return self._cond[idx]
+            return _take_lanes((self._cond,), idx)[0]
         return jnp.stack([self.active[s].cond for s in idx])
-
-    def _scatter(self, idx: np.ndarray, xs, ks, vs) -> None:
-        # the lanes' K/V comes out in the activation dtype (f32 latents
-        # promote a bf16 model's activations); the buffers keep cfg.dtype
-        self._x = self._x.at[idx].set(xs)
-        self._pub_k = self._pub_k.at[idx].set(ks.astype(self._pub_k.dtype))
-        self._pub_v = self._pub_v.at[idx].set(vs.astype(self._pub_v.dtype))
 
     def _lane_bucket(self, slot: int) -> int:
         """The lane's prompt length bucket (0 for class-conditional
@@ -1452,6 +1586,8 @@ class DiffusionServingEngine:
             # built contain the kernels?"
             "kernels": kops.kernel_stats_delta(
                 self._kernel_stats_base, kops.kernel_stats_snapshot()),
+            # lane groups dispatched at each width of the lane ladder
+            "lane_widths": dict(sorted(self.lane_widths.items())),
             "modeled_makespan_s": self.modeled_clock_s,
             "wall_s": wall,
             "throughput_modeled_rps": (len(done) / self.modeled_clock_s

@@ -165,12 +165,14 @@ def test_generate_spans_nest_and_image_is_bitwise(pipe, tmp_path):
     assert len([s for s in spans if s[2] == "exec.record"]) == 1
 
 
-def test_engine_rounds_nest_and_count_padded_lanes(pipe, tmp_path):
-    cfg = pipe.model_cfg
-    xs, conds = _inputs(cfg, 2)
+def _serve_traced(pipe, tmp_path, slots, n):
+    """Serve ``n`` requests through ``slots`` slots, the first three rounds
+    traced, and again untraced; returns (images traced, images untraced,
+    spans)."""
+    xs, conds = _inputs(pipe.model_cfg, n)
 
     def serve(rounds_traced):
-        engine = DiffusionServingEngine(pipe, slots=SLOTS)
+        engine = DiffusionServingEngine(pipe, slots=slots)
         for x, c in zip(xs, conds):
             engine.submit(x, c)
         spans = None
@@ -182,6 +184,15 @@ def test_engine_rounds_nest_and_count_padded_lanes(pipe, tmp_path):
 
     off, _ = serve(False)
     on, spans = serve(True)
+    return on, off, spans
+
+
+def _lane_groups(spans):
+    return [s for s in spans if s[2] in ("exec.warmup", "exec.interval")]
+
+
+def test_engine_rounds_nest_and_count_padded_lanes(pipe, tmp_path):
+    on, off, spans = _serve_traced(pipe, tmp_path, SLOTS, 2)
     assert len(on) == len(off) == 2
     for a, b in zip(on, off):
         np.testing.assert_array_equal(a, b)
@@ -198,8 +209,9 @@ def test_engine_rounds_nest_and_count_padded_lanes(pipe, tmp_path):
             assert _parent(spans, s, ("engine.round",)) is not None, s
     for name in ("engine.admit", "engine.retire"):
         assert len([s for s in spans if s[2] == name]) == 3
-    # two warm-up rounds, then one adaptive interval, each one lane group
-    groups = [s for s in spans if s[2] in ("exec.warmup", "exec.interval")]
+    # two warm-up rounds, then one adaptive interval, each one lane group;
+    # two lanes run at the ladder's rung 3 (1, 3 at three slots)
+    groups = _lane_groups(spans)
     assert [(_parent(spans, g, ("engine.round",)), g[2]) for g in groups] \
         == list(zip(rounds, ["exec.warmup", "exec.warmup", "exec.interval"]))
     assert all(g[3] == {"lanes": 2, "padded": 1} for g in groups)
@@ -208,3 +220,21 @@ def test_engine_rounds_nest_and_count_padded_lanes(pipe, tmp_path):
     assert models and all(
         _parent(spans, s, ("exec.warmup", "exec.interval")) is not None
         and set(s[3]) == {"rows"} for s in models)
+
+
+def test_engine_group_pads_to_its_rung(pipe, tmp_path):
+    """Three lanes at four slots run at rung 4 of the ladder 1, 4: each
+    lane group's span counts the one padded lane, the padding
+    ``padded_row_share.serve`` reads."""
+    on, off, spans = _serve_traced(pipe, tmp_path, 4, 3)
+    assert len(on) == len(off) == 3
+    for a, b in zip(on, off):
+        np.testing.assert_array_equal(a, b)
+    groups = _lane_groups(spans)
+    assert [g[2] for g in groups] == ["exec.warmup", "exec.warmup",
+                                      "exec.interval"]
+    assert all(g[3] == {"lanes": 3, "padded": 1} for g in groups)
+    models = [s for s in spans if s[2] == "exec.model"]
+    assert models and all(
+        _parent(spans, s, ("exec.warmup", "exec.interval")) is not None
+        for s in models)

@@ -7,6 +7,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import types
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from repro.core.pipeline import (StadiConfig, StadiPipeline,
                                  get_stepper_factory)
 from repro.models.diffusion import dit
 from repro.serving import DiffusionServingEngine
+from repro.serving.diffusion_engine import lane_ladder
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -148,6 +150,77 @@ def test_generate_many_matches_generate(setup):
     pipe_cm = _pipe(setup, cost_model=CostModel(t_fixed=1e-3, t_row=1e-3))
     results = pipe_cm.generate_many(xs, conds, slots=2)
     assert all(r.latency_s is not None and r.latency_s > 0 for r in results)
+
+
+# ----------------------------------------------------------------------
+# lane ladder: each group at the narrowest rung that holds its lanes
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("slots,lanes,width", [
+    (8, 1, 1), (8, 2, 8), (8, 3, 8), (8, 4, 8), (8, 5, 8), (8, 8, 8),
+    (3, 1, 1), (3, 2, 3), (3, 3, 3)])
+def test_lane_ladder_picks_narrowest_rung(slots, lanes, width):
+    """The rung is a function of the slot count alone: read it off an
+    engine stand-in rather than compiling one per case."""
+    stand_in = types.SimpleNamespace(slots=slots)
+    idx = DiffusionServingEngine._pad(stand_in, list(range(lanes)))
+    assert len(idx) == width
+    assert list(idx[:lanes]) == list(range(lanes))
+    assert (idx[lanes:] == 0).all()          # padding repeats the first lane
+    # guided groups keep the full width
+    assert len(DiffusionServingEngine._pad(stand_in, list(range(lanes)),
+                                           guided=True)) == slots
+    assert lane_ladder(slots) == [1, slots]
+
+
+def test_lone_and_full_width_lanes_bitwise_match_generate(setup):
+    """One request served alone (width 1) and the same request among
+    ``slots - 1`` others (width ``slots``) give the same image, bitwise
+    equal to a lone ``generate`` under sync; each engine reports the width
+    of every lane group it dispatched."""
+    cfg = setup[0]
+    pipe = _pipe(setup)
+    xs, conds = _requests(cfg, 3, seed=90)
+    ref = np.asarray(pipe.generate(xs[0], conds[0]).image)
+    images = {}
+    for n in (1, 3):
+        engine = DiffusionServingEngine(pipe, slots=3)
+        reqs = [engine.submit(x, c) for x, c in zip(xs[:n], conds[:n])]
+        engine.run_to_completion()
+        images[n] = np.asarray(reqs[0].image)
+        assert engine.stats()["lane_widths"] == {n: len(engine.rounds)}
+    np.testing.assert_array_equal(images[1], ref)
+    np.testing.assert_array_equal(images[3], ref)
+
+
+def test_every_rung_ready_after_construction(setup):
+    """Rounds at every rung compile nothing once an engine is built (the
+    benchmark counts the same monitoring event). The slot count is used
+    by no other test, so its programs are this test's own."""
+    cfg = setup[0]
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == compile_event:
+            compiles.append(secs)
+
+    engine = DiffusionServingEngine(_pipe(setup), slots=5)
+    xs, conds = _requests(cfg, 5, seed=100)
+    # one request first: admission, retirement and submission ops compile
+    # at their first use whatever the width
+    engine.submit(xs[0], conds[0])
+    engine.run_to_completion()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for n in (2, 3, 5, 1):                 # widths 5, 5, 5, 1
+            for x, c in zip(xs[:n], conds[:n]):
+                engine.submit(x, c)
+            engine.run_to_completion()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert set(engine.stats()["lane_widths"]) == {1, 5}
 
 
 # ----------------------------------------------------------------------
